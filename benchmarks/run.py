@@ -45,6 +45,8 @@ def main() -> None:
                          "SIFT1B operating point — recall delta + "
                          "storage-byte ratio vs float32)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     mods = MODULES
     if args.only:
         want = args.only.split(",")

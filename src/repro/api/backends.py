@@ -188,8 +188,24 @@ class PartitionedBackend:
         else:
             self.dev_vectors = self.dev_sqnorms = None
 
+    @staticmethod
+    def _check_fused(spec: IndexSpec) -> None:
+        """fused_hops > 1 runs layer 0 in the fused_traversal_pallas kernel,
+        which Mosaic does not compile yet: refuse on a TPU rather than
+        fall back to another path. Off-TPU it runs in interpret mode.
+        PQ never takes the kernel (its layer 0 is always hop-stepped)."""
+        from repro.kernels.ops import default_interpret
+        if (spec.fused_hops > 1 and spec.dtype != "pq"
+                and not default_interpret()):
+            raise NotImplementedError(
+                f"fused_hops={spec.fused_hops} needs the "
+                f"fused_traversal_pallas kernel (kernels/traversal.py), "
+                f"which does not compile for "
+                f"{jax.default_backend()!r} yet; build with fused_hops=1")
+
     @classmethod
     def build(cls, vectors: np.ndarray, spec: IndexSpec, mesh=None):
+        cls._check_fused(spec)
         p = cls.forced_partitions or spec.num_partitions
         # dtype="pq": `vectors` are the ORIGINAL float32 rows — the graphs
         # are built full-precision and quantize_db_vectors re-encodes the
@@ -251,6 +267,7 @@ class PartitionedBackend:
 
     @classmethod
     def from_state(cls, spec: IndexSpec, leaves: dict, mesh=None):
+        cls._check_fused(spec)
         db = hg.DeviceDB(**{k.split("/", 1)[1]: jnp.asarray(v)
                             for k, v in leaves.items()
                             if k.startswith("db/")})

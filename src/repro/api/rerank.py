@@ -42,7 +42,8 @@ def batched_rerank(vectors, sqnorms, queries, cand_ids, k: int,
     q = queries.astype(jnp.float32)
     qsq = jnp.einsum("bd,bd->b", q, q)
     vecs = vectors[safe]                          # [B, C, D]
-    dot = jnp.einsum("bcd,bd->bc", vecs, q)
+    dot = jnp.einsum("bcd,bd->bc", vecs, q,
+                     precision=jax.lax.Precision.HIGHEST)
     d = metric_distance(metric, dot, sqnorms[safe], qsq[:, None])
     d = jnp.where(valid, d, jnp.inf)
 

@@ -31,6 +31,9 @@ def bruteforce_topk(vectors, sqnorms, queries, k: int = 10, chunk: int = 4096,
              the +inf sqnorm is the pad marker for every metric). May hold
              uint8/int8 codes (quantized path): each chunk is cast to f32
              at the matmul, so distances are exact code-space values.
+             f32 rows take the matmul at HIGHEST precision (a TPU's default
+             rounds f32 operands to bf16); 8-bit codes and the code-valued
+             queries they are searched with are exact in bf16 already.
     queries: [B, D]
     returns: ids [B, k] int32, dists [B, k] float32
     """
@@ -39,6 +42,9 @@ def bruteforce_topk(vectors, sqnorms, queries, k: int = 10, chunk: int = 4096,
     assert n % chunk == 0, "pad the database to a multiple of `chunk`"
     queries = queries.astype(jnp.float32)
     qsq = jnp.einsum("bd,bd->b", queries, queries)
+    precision = (jax.lax.Precision.DEFAULT
+                 if jnp.issubdtype(vectors.dtype, jnp.integer)
+                 else jax.lax.Precision.HIGHEST)
 
     vecs = vectors.reshape(n // chunk, chunk, d)
     sqs = sqnorms.reshape(n // chunk, chunk)
@@ -46,7 +52,8 @@ def bruteforce_topk(vectors, sqnorms, queries, k: int = 10, chunk: int = 4096,
     def step(carry, xs):
         run_d, run_i = carry               # [B, k] sorted ascending
         v, s, off = xs
-        dot = queries @ v.T.astype(jnp.float32)
+        dot = jnp.matmul(queries, v.T.astype(jnp.float32),
+                         precision=precision)
         d2 = metric_distance(metric, dot, s[None, :], qsq[:, None])
         d2 = jnp.where(jnp.isinf(s)[None, :], jnp.inf, d2)
         cd, ci = jax.lax.top_k(-d2, k)     # [B, k] largest of -d2 == smallest d2

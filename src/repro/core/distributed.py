@@ -23,7 +23,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core.hnsw_graph import DeviceDB
 from repro.core.partitioned import PartitionedDB, merge_topk
@@ -74,8 +73,8 @@ def make_distributed_search(mesh, p: SearchParams, maxM0: int,
     out_specs = (qspec, qspec, qspec)
 
     @functools.partial(
-        shard_map, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False)
+        jax.shard_map, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False)
     def _search(db_local: DeviceDB, queries, *lut):
         lut = lut[0] if lut else None
         # stage 1: every local partition searches the local query shard.

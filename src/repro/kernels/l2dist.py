@@ -22,9 +22,27 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import COMPILER_PARAMS as _COMPILER_PARAMS
+__all__ = ["l2dist_pallas", "as_f32", "norm_specs"]
 
-__all__ = ["l2dist_pallas"]
+
+def as_f32(x):
+    """In-kernel cast to f32. Integer codes go through int32: Mosaic has no
+    direct uint8 -> f32 cast, and the detour is exact for 8-bit values."""
+    if jnp.issubdtype(x.dtype, jnp.integer):
+        x = x.astype(jnp.int32)
+    return x.astype(jnp.float32)
+
+
+def norm_specs(block_q, block_x, grid_rank):
+    """BlockSpecs for the squared-norm operands, passed 2-D as qsq [Bq, 1]
+    and xsq [1, Bx]: a 1-D f32 block does not get the (8, 128) tiling
+    Mosaic requires. The kernel adds qsq_ref[...] + xsq_ref[...] and gets
+    the [block_q, block_x] tile by broadcasting."""
+    if grid_rank == 3:
+        return [pl.BlockSpec((block_q, 1), lambda i, j, k: (i, 0)),
+                pl.BlockSpec((1, block_x), lambda i, j, k: (0, j))]
+    return [pl.BlockSpec((block_q, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, block_x), lambda i, j: (0, j))]
 
 
 def _kernel(qsq_ref, xsq_ref, q_ref, x_ref, out_ref, *, metric):
@@ -36,18 +54,19 @@ def _kernel(qsq_ref, xsq_ref, q_ref, x_ref, out_ref, *, metric):
         # is shared. l2: ||q||^2 + ||x||^2 - 2 q.x; ip: -q.x; cosine
         # (unit-norm inputs): 1 - q.x.
         if metric == "l2":
-            out_ref[...] = qsq_ref[...][:, None] + xsq_ref[...][None, :]
+            out_ref[...] = qsq_ref[...] + xsq_ref[...]
         elif metric == "cosine":
             out_ref[...] = jnp.ones_like(out_ref[...])
         else:
             out_ref[...] = jnp.zeros_like(out_ref[...])
 
-    q = q_ref[...].astype(jnp.float32)
-    x = x_ref[...].astype(jnp.float32)
+    q = as_f32(q_ref[...])
+    x = as_f32(x_ref[...])
     scale = -2.0 if metric == "l2" else -1.0
+    # HIGHEST: the MXU's default single bf16 pass loses f32 digits on TPU
     out_ref[...] += scale * jax.lax.dot_general(
-        q, x, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
+        q, x, (((1,), (1,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
 
 
 @functools.partial(
@@ -80,16 +99,14 @@ def l2dist_pallas(
     return pl.pallas_call(
         functools.partial(_kernel, metric=metric),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_q,), lambda i, j, k: (i,)),
-            pl.BlockSpec((block_x,), lambda i, j, k: (j,)),
+        in_specs=norm_specs(block_q, block_x, 3) + [
             pl.BlockSpec((block_q, block_d), lambda i, j, k: (i, k)),
             pl.BlockSpec((block_x, block_d), lambda i, j, k: (j, k)),
         ],
         out_specs=pl.BlockSpec((block_q, block_x), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((bq, bx), jnp.float32),
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
-    )(qsq, xsq, queries, xs)
+    )(qsq[:, None], xsq[None, :], queries, xs)

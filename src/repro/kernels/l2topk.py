@@ -21,9 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import COMPILER_PARAMS as _COMPILER_PARAMS
-
-from repro.kernels.topk import _select_k
+from repro.kernels.l2dist import as_f32, norm_specs
+from repro.kernels.topk import _fold_block
 
 __all__ = ["l2topk_pallas"]
 
@@ -37,19 +36,16 @@ def _make_kernel(k: int, block_x: int):
             run_v[...] = jnp.full_like(run_v, jnp.inf)
             run_i[...] = jnp.full_like(run_i, -1)
 
-        q = q_ref[...].astype(jnp.float32)                  # [bq, D]
-        x = x_ref[...].astype(jnp.float32)                  # [bx, D]
-        d2 = qsq_ref[...][:, None] + xsq_ref[...][None, :] - 2.0 * jax.lax.dot_general(
-            q, x, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
+        q = as_f32(q_ref[...])                              # [bq, D]
+        x = as_f32(x_ref[...])                              # [bx, D]
+        # HIGHEST: the MXU's default single bf16 pass loses f32 digits
+        d2 = qsq_ref[...] + xsq_ref[...] - 2.0 * jax.lax.dot_general(
+            q, x, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
         d2 = jnp.maximum(d2, 0.0)                           # +inf padding survives
         cols = jax.lax.broadcasted_iota(jnp.int32, d2.shape, 1) + j * block_x
-        bv, bi = _select_k(d2, cols, k)
-        cat_v = jnp.concatenate([run_v[...], bv], axis=1)
-        cat_i = jnp.concatenate([run_i[...], bi], axis=1)
-        mv, mi = _select_k(cat_v, cat_i, k)
-        run_v[...] = mv
-        run_i[...] = mi
+        _fold_block(run_v, run_i, d2, cols, k)
 
         @pl.when(j == pl.num_programs(1) - 1)
         def _flush():
@@ -85,9 +81,7 @@ def l2topk_pallas(
     return pl.pallas_call(
         _make_kernel(k, block_x),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_q,), lambda i, j: (i,)),
-            pl.BlockSpec((block_x,), lambda i, j: (j,)),
+        in_specs=norm_specs(block_q, block_x, 2) + [
             pl.BlockSpec((block_q, d), lambda i, j: (i, 0)),
             pl.BlockSpec((block_x, d), lambda i, j: (j, 0)),
         ],
@@ -103,8 +97,8 @@ def l2topk_pallas(
             pltpu.VMEM((block_q, k), jnp.float32),
             pltpu.VMEM((block_q, k), jnp.int32),
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
-    )(qsq, xsq, queries, xs)
+    )(qsq[:, None], xsq[None, :], queries, xs)

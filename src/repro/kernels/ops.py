@@ -4,9 +4,12 @@ Handles arbitrary (unaligned) shapes by padding to block multiples — the
 software analogue of the paper's database restructuring: callers never pay
 for unaligned accesses because alignment is established once at the edge.
 
-`interpret` defaults to True off-TPU (this container is CPU-only; interpret
-mode executes the kernel bodies exactly, so correctness tests are real),
-and to False on TPU where the Mosaic lowering runs.
+`interpret` defaults to True off-TPU (interpret mode executes the kernel
+bodies exactly, so CPU correctness tests are real) and to False on TPU,
+where the kernels compile through Mosaic. Every ANN kernel here does for
+TPU v5e (tests/test_tpu_compile.py) except `fused_layer0`: on a TPU the
+backends refuse `fused_hops > 1` at build instead of reaching it.
+(`flash_attention`, the LM substrate's, is not compiled there.)
 """
 
 from __future__ import annotations
@@ -214,9 +217,10 @@ def fused_layer0(vectors, sqnorms, l0_nbrs, queries, qsq,
     Unlike the other wrappers, no padding happens here: the restructured
     DB's tables (hnsw_graph.restructure) are already lane-aligned, and the
     beam-state shapes come from SearchParams.resolve. The wrapper exists
-    for the interpret dispatch (CPU containers run the kernel body exactly;
-    TPU runs the Mosaic lowering) and is called from inside batch_search's
-    jit, so it does not re-jit."""
+    for the interpret dispatch (the CPU runs the kernel body exactly; Mosaic
+    does not lower this kernel yet, so TPU backends refuse fused_hops > 1
+    at build) and is called from inside batch_search's jit, so it does not
+    re-jit."""
     interpret = default_interpret() if interpret is None else interpret
     return fused_traversal_pallas(
         vectors, sqnorms, l0_nbrs, queries, qsq,

@@ -10,7 +10,9 @@ operating point:
     VMEM traffic of `l2dist.py`),
   * each tile is cast to f32 on-core and hits the MXU with f32
     accumulation, which is EXACT for 8-bit codes up to ~256 dims: every
-    partial dot product is an integer < 2^24, below the f32 mantissa;
+    partial dot product is an integer < 2^24, below the f32 mantissa, and
+    8-bit values pass the MXU's single bf16 pass unrounded, so the default
+    precision suffices (the f32 kernels need HIGHEST);
   * `out_scale` (the quantizer's `scale**2`) converts code-space squared
     L2 back to real units inside the kernel, so callers never see codes.
 
@@ -38,8 +40,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import COMPILER_PARAMS as _COMPILER_PARAMS
-from repro.kernels.topk import _select_k
+from repro.kernels.l2dist import as_f32, norm_specs
+from repro.kernels.topk import _fold_block
 
 __all__ = ["l2dist_q_pallas", "l2topk_q_pallas",
            "pq_adc_pallas", "pq_topk_pallas"]
@@ -55,11 +57,11 @@ def _dist_kernel(qsq_ref, xsq_ref, q_ref, x_ref, out_ref, *, out_scale):
 
     @pl.when(k == 0)
     def _init():
-        out_ref[...] = qsq_ref[...][:, None] + xsq_ref[...][None, :]
+        out_ref[...] = qsq_ref[...] + xsq_ref[...]
 
     # codes live in VMEM at 1 byte/lane; the cast to f32 happens on-core
-    q = q_ref[...].astype(jnp.float32)
-    x = x_ref[...].astype(jnp.float32)
+    q = as_f32(q_ref[...])
+    x = as_f32(x_ref[...])
     out_ref[...] += -2.0 * jax.lax.dot_general(
         q, x, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )
@@ -102,19 +104,17 @@ def l2dist_q_pallas(
     return pl.pallas_call(
         functools.partial(_dist_kernel, out_scale=out_scale),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_q,), lambda i, j, k: (i,)),
-            pl.BlockSpec((block_x,), lambda i, j, k: (j,)),
+        in_specs=norm_specs(block_q, block_x, 3) + [
             pl.BlockSpec((block_q, block_d), lambda i, j, k: (i, k)),
             pl.BlockSpec((block_x, block_d), lambda i, j, k: (j, k)),
         ],
         out_specs=pl.BlockSpec((block_q, block_x), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((bq, bx), jnp.float32),
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
-    )(qsq, xsq, queries, xs)
+    )(qsq[:, None], xsq[None, :], queries, xs)
 
 
 def _topk_kernel(k: int, block_x: int, out_scale: float):
@@ -127,20 +127,14 @@ def _topk_kernel(k: int, block_x: int, out_scale: float):
             run_v[...] = jnp.full_like(run_v, jnp.inf)
             run_i[...] = jnp.full_like(run_i, -1)
 
-        q = q_ref[...].astype(jnp.float32)                  # [bq, D] codes
-        x = x_ref[...].astype(jnp.float32)                  # [bx, D] codes
-        d2 = qsq_ref[...][:, None] + xsq_ref[...][None, :] - 2.0 * \
-            jax.lax.dot_general(
-                q, x, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
+        q = as_f32(q_ref[...])                              # [bq, D] codes
+        x = as_f32(x_ref[...])                              # [bx, D] codes
+        d2 = qsq_ref[...] + xsq_ref[...] - 2.0 * jax.lax.dot_general(
+            q, x, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
         d2 = jnp.maximum(d2, 0.0)                           # +inf pad survives
         cols = jax.lax.broadcasted_iota(jnp.int32, d2.shape, 1) + j * block_x
-        bv, bi = _select_k(d2, cols, k)
-        cat_v = jnp.concatenate([run_v[...], bv], axis=1)
-        cat_i = jnp.concatenate([run_i[...], bi], axis=1)
-        mv, mi = _select_k(cat_v, cat_i, k)
-        run_v[...] = mv
-        run_i[...] = mi
+        _fold_block(run_v, run_i, d2, cols, k)
 
         @pl.when(j == pl.num_programs(1) - 1)
         def _flush():
@@ -179,9 +173,7 @@ def l2topk_q_pallas(
     return pl.pallas_call(
         _topk_kernel(k, block_x, out_scale),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_q,), lambda i, j: (i,)),
-            pl.BlockSpec((block_x,), lambda i, j: (j,)),
+        in_specs=norm_specs(block_q, block_x, 2) + [
             pl.BlockSpec((block_q, d), lambda i, j: (i, 0)),
             pl.BlockSpec((block_x, d), lambda i, j: (j, 0)),
         ],
@@ -197,11 +189,11 @@ def l2topk_q_pallas(
             pltpu.VMEM((block_q, k), jnp.float32),
             pltpu.VMEM((block_q, k), jnp.int32),
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
-    )(qsq, xsq, queries, xs)
+    )(qsq[:, None], xsq[None, :], queries, xs)
 
 
 # ---------------------------------------------------------------------------
@@ -212,21 +204,29 @@ def l2topk_q_pallas(
 def _pq_block_dists(lut, codes, xpad):
     """[bq, m, 256] LUT x [bx, m] codes -> [bq, bx] ADC distances.
 
-    One gather + one add PER SUBSPACE, in subspace order m=0..M-1 — the
-    PQ extension of core.search's mul+sum reduction-order rule. The numpy
-    refs accumulate in the same order, so kernel == ref bitwise; every
-    engine path gathers from the same `build_pq_lut` tables, so changing
-    this order (tree reduction, einsum) breaks cross-backend parity.
-    `xpad` is +inf on database padding rows (inf + finite stays inf, the
-    same marker trick as the xsq pad in the integer kernels).
+    One table lookup + one add PER SUBSPACE, in subspace order m=0..M-1 —
+    the PQ extension of core.search's mul+sum reduction-order rule. The
+    numpy refs accumulate in the same order, so kernel == ref bitwise;
+    every engine path gathers from the same `build_pq_lut` tables, so
+    changing this order (tree reduction, einsum) breaks cross-backend
+    parity. The lookup is a product with the codes' one-hot rows at
+    HIGHEST precision (Mosaic has no lane gather): each output sums one
+    table entry times 1 and 255 zeros, so it equals the entry exactly.
+    `xpad` is [1, bx], +inf on database padding rows (inf + finite stays
+    inf, the same marker trick as the xsq pad in the integer kernels).
     """
     m = lut.shape[1]
     codes = codes.astype(jnp.int32)
+    entry = jax.lax.broadcasted_iota(jnp.int32, (codes.shape[0], 256), 1)
     acc = jnp.zeros((lut.shape[0], codes.shape[0]), jnp.float32)
-    acc = acc + xpad.astype(jnp.float32)[None, :]
+    acc = acc + xpad.astype(jnp.float32)
     for mi in range(m):
-        # lut[:, mi, :] is [bq, 256]; codes[:, mi] is [bx] -> [bq, bx]
-        acc = acc + jnp.take(lut[:, mi, :], codes[:, mi], axis=1)
+        # lut[:, mi, :] is [bq, 256]; codes[:, mi] one-hot is [bx, 256]
+        onehot = (codes[:, mi:mi + 1] == entry).astype(jnp.float32)
+        acc = acc + jax.lax.dot_general(
+            lut[:, mi, :], onehot, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
     return acc
 
 
@@ -250,10 +250,9 @@ def pq_adc_pallas(
 
     The streamed database is M bytes/row; each program holds block_q LUTs
     (block_q * M * 1KB of VMEM) and a block_x x M code tile. Dims must
-    divide the block sizes (ops.pq_adc pads arbitrary shapes). Note the
-    code tile's last dim is M (not lane-padded): fine in interpret mode
-    and exactly the point of PQ — on a real TPU lowering the codes would
-    ride an int8-tiled layout.
+    divide the block sizes (ops.pq_adc pads arbitrary shapes). The code
+    tile's last dim is M, the whole row: the database streams at M bytes
+    per row, which is the point of PQ.
     """
     bq, m, k256 = luts.shape
     bx = codes.shape[0]
@@ -267,15 +266,15 @@ def pq_adc_pallas(
         in_specs=[
             pl.BlockSpec((block_q, m, 256), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((block_x, m), lambda i, j: (j, 0)),
-            pl.BlockSpec((block_x,), lambda i, j: (j,)),
+            pl.BlockSpec((1, block_x), lambda i, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((block_q, block_x), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((bq, bx), jnp.float32),
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")
         ),
         interpret=interpret,
-    )(luts, codes, xpad)
+    )(luts, codes, xpad[None, :])
 
 
 def _pq_topk_kernel(k: int, block_x: int):
@@ -290,12 +289,7 @@ def _pq_topk_kernel(k: int, block_x: int):
 
         d2 = _pq_block_dists(lut_ref[...], codes_ref[...], xpad_ref[...])
         cols = jax.lax.broadcasted_iota(jnp.int32, d2.shape, 1) + j * block_x
-        bv, bi = _select_k(d2, cols, k)
-        cat_v = jnp.concatenate([run_v[...], bv], axis=1)
-        cat_i = jnp.concatenate([run_i[...], bi], axis=1)
-        mv, mi = _select_k(cat_v, cat_i, k)
-        run_v[...] = mv
-        run_i[...] = mi
+        _fold_block(run_v, run_i, d2, cols, k)
 
         @pl.when(j == pl.num_programs(1) - 1)
         def _flush():
@@ -331,7 +325,7 @@ def pq_topk_pallas(
         in_specs=[
             pl.BlockSpec((block_q, m, 256), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((block_x, m), lambda i, j: (j, 0)),
-            pl.BlockSpec((block_x,), lambda i, j: (j,)),
+            pl.BlockSpec((1, block_x), lambda i, j: (0, j)),
         ],
         out_specs=[
             pl.BlockSpec((block_q, k), lambda i, j: (i, 0)),
@@ -345,8 +339,8 @@ def pq_topk_pallas(
             pltpu.VMEM((block_q, k), jnp.float32),
             pltpu.VMEM((block_q, k), jnp.int32),
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
-    )(luts, codes, xpad)
+    )(luts, codes, xpad[None, :])

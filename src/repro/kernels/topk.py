@@ -4,9 +4,10 @@ The paper keeps the final list sorted with a *parallel insertion sort*: all
 list entries are compared against the incoming distance at once and the
 insert rank is the popcount of the comparison bit-vector (§5.2.6, Fig. 7).
 The TPU rendition below streams column blocks through VMEM and maintains a
-running sorted top-k per row in scratch; each block is reduced with k
-vectorized argmin/mask passes (a k-step selection network — every comparison
-of the paper's bit-vector happens lane-parallel on the VPU).
+running sorted top-k per row in scratch; the running list and each block
+are reduced together with k vectorized min/mask passes (a k-step selection
+network — every comparison of the paper's bit-vector happens lane-parallel
+on the VPU).
 
 Also reused by MoE routing (top-k expert choice = 1-hop nearest-centroid).
 """
@@ -20,29 +21,54 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import COMPILER_PARAMS as _COMPILER_PARAMS
-
 __all__ = ["topk_pallas"]
 
 
-def _select_k(vals, ids, k):
-    """k-step selection: returns ([rows, k] ascending values, ids)."""
-    rows, _ = vals.shape
+def _select_k(parts, k):
+    """k-step selection over the lane concatenation of `parts`, a list of
+    (vals [rows, n_i], ids [rows, n_i]) pairs; returns ([rows, k] ascending
+    values, ids). Ties go to the earlier lane, as argmin would pick.
+
+    Each step takes a row min, then the first lane holding it (a min over
+    an iota mask) and that lane's id (a one-hot masked sum): reductions and
+    selects only, no gather or scatter, which is what Mosaic lowers."""
+    rows = parts[0][0].shape[0]
+    offs = [0]
+    for v, _ in parts:
+        offs.append(offs[-1] + v.shape[1])
+    lanes = [jax.lax.broadcasted_iota(jnp.int32, v.shape, 1) + o
+             for (v, _), o in zip(parts, offs)]
+    kcol = jax.lax.broadcasted_iota(jnp.int32, (rows, k), 1)
 
     def step(t, carry):
         vals, out_v, out_i = carry
-        j = jnp.argmin(vals, axis=1)                       # [rows]
-        row = jnp.arange(rows)
-        v = vals[row, j]
-        out_v = jax.lax.dynamic_update_index_in_dim(out_v, v, t, 1)
-        out_i = jax.lax.dynamic_update_index_in_dim(out_i, ids[row, j], t, 1)
-        vals = vals.at[row, j].set(jnp.inf)
+        m = functools.reduce(jnp.minimum, [
+            jnp.min(v, axis=1, keepdims=True) for v in vals])
+        pos = functools.reduce(jnp.minimum, [
+            jnp.min(jnp.where(v == m, ln, offs[-1]), axis=1, keepdims=True)
+            for v, ln in zip(vals, lanes)])
+        hits = [ln == pos for ln in lanes]
+        sel = functools.reduce(jnp.add, [
+            jnp.sum(jnp.where(h, i, 0), axis=1, keepdims=True)
+            for h, (_, i) in zip(hits, parts)])
+        out_v = jnp.where(kcol == t, m, out_v)
+        out_i = jnp.where(kcol == t, sel, out_i)
+        vals = [jnp.where(h, jnp.inf, v) for h, v in zip(hits, vals)]
         return vals, out_v, out_i
 
-    out_v = jnp.zeros((rows, k), vals.dtype)
-    out_i = jnp.zeros((rows, k), ids.dtype)
-    _, out_v, out_i = jax.lax.fori_loop(0, k, step, (vals, out_v, out_i))
+    out_v = jnp.zeros((rows, k), jnp.float32)
+    out_i = jnp.zeros((rows, k), jnp.int32)
+    _, out_v, out_i = jax.lax.fori_loop(
+        0, k, step, ([v for v, _ in parts], out_v, out_i))
     return out_v, out_i
+
+
+def _fold_block(run_v, run_i, vals, ids, k):
+    """Fold one block of (vals, ids) into the running top-k scratch refs.
+    Selecting over [running list, block] at once equals the block's top-k
+    merged into the running list, ties keeping the running entry first."""
+    run_v[...], run_i[...] = _select_k(
+        [(run_v[...], run_i[...]), (vals, ids)], k)
 
 
 def _make_kernel(k: int, block_x: int):
@@ -56,13 +82,7 @@ def _make_kernel(k: int, block_x: int):
 
         x = x_ref[...].astype(jnp.float32)                 # [block_b, block_x]
         cols = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1) + j * block_x
-        bv, bi = _select_k(x, cols, k)                     # block top-k
-        # merge running + block candidates (2k) down to k.
-        cat_v = jnp.concatenate([run_v[...], bv], axis=1)
-        cat_i = jnp.concatenate([run_i[...], bi], axis=1)
-        mv, mi = _select_k(cat_v, cat_i, k)
-        run_v[...] = mv
-        run_i[...] = mi
+        _fold_block(run_v, run_i, x, cols, k)
 
         @pl.when(j == pl.num_programs(1) - 1)
         def _flush():
@@ -103,7 +123,7 @@ def topk_pallas(
             pltpu.VMEM((block_b, k), jnp.float32),
             pltpu.VMEM((block_b, k), jnp.int32),
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -30,8 +30,11 @@ as comparison-matrix rank computations (the paper's parallel insertion sort
 computes insert positions as popcounts of comparison bit-vectors — §5.2.4):
 ``searchsorted(b, a, 'left') == #(b_j < a_i)`` and stable-argsort position
 ``pos_i == #(d_j < d_i) + #(j < i, d_j == d_i)``. Identical outputs, but
-matmul/reduction-shaped instead of sort-shaped — which is what lowers on a
-TPU. The kernel imports nothing from repro.core (core imports kernels.ops
+matmul/reduction-shaped instead of sort-shaped. Mosaic does not lower the
+kernel yet (its (1, w) lane blocks, the DMA index read out of a vector, and
+the bitmap's dynamic gather/scatter), so it runs in interpret mode only and
+a TPU backend refuses `fused_hops > 1` at build. The kernel imports nothing
+from repro.core (core imports kernels.ops
 lazily for dispatch, so the dependency arrow must point one way only).
 """
 
@@ -44,7 +47,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import COMPILER_PARAMS as _COMPILER_PARAMS
 
 __all__ = ["fused_traversal_pallas"]
 
@@ -289,7 +291,8 @@ def fused_traversal_pallas(
             pltpu.SemaphoreType.DMA((M0,)),
             pltpu.SemaphoreType.DMA((M0,)),
         ],
-        compiler_params=_COMPILER_PARAMS(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
     )(qsq[:, None], queries, cand_d, cand_i, fin_d, fin_i, visited,
       hops[:, None], calcs[:, None], vectors, sqnorms.reshape(N, 1),

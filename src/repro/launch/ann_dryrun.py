@@ -1,5 +1,6 @@
 import os
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=512").strip()
+os.environ["JAX_PLATFORMS"] = "cpu"  # a modeling tool: never take a chip
 
 """SIFT1B-scale dry-run of the paper's engine itself on the production mesh.
 

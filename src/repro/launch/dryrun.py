@@ -1,9 +1,10 @@
 import os
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=512").strip()
+os.environ["JAX_PLATFORMS"] = "cpu"  # a modeling tool: never take a chip
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
-The two lines above MUST run before any jax import (device count locks on
+The three lines above MUST run before any jax import (device count locks on
 first init). This proves the distribution config is coherent without real
 hardware: a sharding mismatch, compile-time OOM, or unsupported collective
 here is a bug in the system.
@@ -148,8 +149,6 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool, fsdp_serve=None,
         rec["compile_s"] = round(time.time() - t1, 2)
 
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):     # older jax: one dict per device
-        ca = ca[0] if ca else {}
     # NOTE: XLA counts while-loop bodies once (verified experimentally), so
     # these raw numbers undercount scanned models; the roofline terms below
     # use the loop-aware analytic model (launch/costmodel.py), calibrated

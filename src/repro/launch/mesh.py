@@ -18,19 +18,14 @@ __all__ = ["make_production_mesh", "make_mesh", "dp_axes", "mesh_shape",
 
 
 def make_mesh(shape, axes):
-    """jax.make_mesh with Auto axis_types where the jax version has them."""
-    kwargs = {}
-    if hasattr(jax.sharding, "AxisType"):    # absent on older jax
-        kwargs["axis_types"] = (jax.sharding.AxisType.Auto,) * len(axes)
-    return jax.make_mesh(shape, axes, **kwargs)
+    """jax.make_mesh with Auto axis_types."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def enter_mesh(mesh):
-    """Context manager activating `mesh`: jax.set_mesh on new jax, the
-    legacy `with mesh:` global-mesh context on older releases."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    """Context manager activating `mesh` (jax.set_mesh)."""
+    return jax.set_mesh(mesh)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -204,6 +204,8 @@ def main(argv=None):
                          "JSON dump here at drain (async path only)")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from repro.obs import PeriodicExporter, TRACER, write_snapshot
     if args.trace or args.trace_out:
         TRACER.configure(enabled=True, sample_rate=args.trace_sample)

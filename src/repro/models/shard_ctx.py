@@ -20,11 +20,8 @@ __all__ = ["activation_sharding", "constrain", "dp", "tp"]
 
 
 def _mesh_shape():
-    """Axis-name -> size of the ambient mesh, across jax versions."""
-    if hasattr(jax.sharding, "get_abstract_mesh"):
-        return jax.sharding.get_abstract_mesh().shape
-    from jax._src.mesh import thread_resources   # legacy global-mesh context
-    return dict(thread_resources.env.physical_mesh.shape)
+    """Axis-name -> size of the ambient mesh."""
+    return jax.sharding.get_abstract_mesh().shape
 
 
 @contextlib.contextmanager

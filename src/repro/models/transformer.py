@@ -22,24 +22,6 @@ from repro.models import moe as M
 from repro.models import shard_ctx
 from repro.models import ssm as S
 
-_BARRIER_AD: bool | None = None
-
-
-def _barrier_ad() -> bool:
-    """Old jax lacks the optimization_barrier differentiation rule; the
-    barrier is only a layout hint, so skip it there (2x carry-stack memory
-    on 0.4-era CPU builds is acceptable; correctness is unchanged)."""
-    global _BARRIER_AD
-    if _BARRIER_AD is None:
-        try:
-            jax.grad(lambda v: jax.lax.optimization_barrier(v).sum())(
-                jnp.ones((2,)))
-            _BARRIER_AD = True
-        except NotImplementedError:
-            _BARRIER_AD = False
-    return _BARRIER_AD
-
-
 __all__ = ["LayerSpec", "ModelConfig", "init_params", "forward", "init_cache",
            "compute_logits", "chunked_xent"]
 
@@ -320,8 +302,7 @@ def forward(params, cfg: ModelConfig, inputs, *, mode: str, cache=None, pos=0,
         # The optimization barrier keeps XLA from hoisting the layer-entry
         # bf16->f32 convert out of the scan — without it the carry stack is
         # stored f32 AND full-T (2x + gather blowup on 40-period models).
-        if _barrier_ad():
-            x = jax.lax.optimization_barrier(x)
+        x = jax.lax.optimization_barrier(x)
         x = shard_ctx.constrain(x, ("dp", "tp", None))
         new_pc = {}
         aux_p = 0.0

@@ -24,6 +24,7 @@ replica runs a single worker thread, so batches on one replica serialize
 
 from __future__ import annotations
 
+import copy
 import threading
 import time
 from concurrent.futures import Future, ThreadPoolExecutor
@@ -211,34 +212,22 @@ def _clone_service(service, i: int):
 
     devices = jax.devices()
     if len(devices) > 1 and spec.backend in ("exact", "hnsw", "partitioned"):
-        dev = devices[i % len(devices)]
-        clone = _place_on_device(service, dev)
-        if clone is not None:
-            return clone, False
+        return _place_on_device(service, devices[i % len(devices)]), False
     # distributed (spans the mesh already) and single-device hosts: share
     return service, False
 
 
 def _place_on_device(service, dev):
-    """In-memory backend copy with its arrays on `dev`; None if the backend
-    shape is unrecognized (caller falls back to sharing)."""
+    """In-memory backend copy with every device array it holds committed
+    to `dev`: the graph or scan tables, the rerank tables, and the PQ
+    codebooks and codes. Queries arrive uncommitted, so each search and
+    each PQ table build then runs on `dev`."""
     from repro.api.service import SearchService
 
-    backend = service.backend
-    put = lambda t: jax.tree.map(lambda a: jax.device_put(a, dev), t)
-    if hasattr(backend, "pdb"):            # partitioned / hnsw
-        from repro.core.partitioned import PartitionedDB
-        pdb = PartitionedDB(db=put(backend.pdb.db),
-                            num_partitions=backend.pdb.num_partitions,
-                            dim=backend.pdb.dim)
-        clone = type(backend)(service.spec, pdb, raw=backend.raw)
-        if clone.dev_vectors is not None:   # rerank tables follow the graph
-            clone.dev_vectors = put(clone.dev_vectors)
-            clone.dev_sqnorms = put(clone.dev_sqnorms)
-        return SearchService(service.spec, clone)
-    if hasattr(backend, "vectors") and hasattr(backend, "sqnorms"):  # exact
-        clone = type(backend)(service.spec, backend.raw)
-        clone.vectors = put(clone.vectors)
-        clone.sqnorms = put(clone.sqnorms)
-        return SearchService(service.spec, clone)
-    return None
+    def put(a):
+        return jax.device_put(a, dev) if isinstance(a, jax.Array) else a
+
+    clone = copy.copy(service.backend)
+    for name, value in vars(service.backend).items():
+        setattr(clone, name, jax.tree.map(put, value))
+    return SearchService(service.spec, clone)

@@ -46,10 +46,7 @@ def test_prefill_flops_match_xla(arch, tol):
     else:
         inputs = jnp.zeros((B, T, cfg.d_model), jnp.float32)
     lowered = prefill_step.lower(params, {"inputs": inputs}, cache, cfg)
-    ca = lowered.compile().cost_analysis()
-    if isinstance(ca, (list, tuple)):     # older jax: one dict per device
-        ca = ca[0]
-    got = ca["flops"]
+    got = lowered.compile().cost_analysis()["flops"]
     want = cell_costs(cfg, "prefill", T, B, n_devices=1, model_ax=1,
                       dp_ax=1, fsdp=False).flops_per_dev
     # analytic excludes elementwise ops XLA counts (norms, rope, softmax),

@@ -270,3 +270,21 @@ def test_async_serve_is_bit_identical_to_direct(backend, backend_zoo):
     np.testing.assert_allclose(np.stack([r.dists for r in res]),
                                np.asarray(direct.dists),
                                rtol=1e-3, atol=2.0)
+
+
+def test_replicas_hold_their_arrays_on_their_own_device():
+    """4 virtual devices, in a subprocess so this process keeps its
+    one-device view: replica i's graph, scan and rerank tables, PQ
+    codebooks and codes all live on device i, and it answers there."""
+    import os
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, PYTHONPATH=os.path.join(root, "src"))
+    env.pop("XLA_FLAGS", None)
+    r = subprocess.run(
+        [sys.executable, os.path.join(root, "tests", "helpers",
+                                      "replica_check.py")],
+        capture_output=True, text=True, timeout=420, env=env, cwd=root)
+    assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-2000:]
+    assert "REPLICAS OK" in r.stdout

@@ -294,3 +294,21 @@ def test_superstep_mode_strictly_reduces_bytes_read(backend_zoo):
     assert snap4["bytes_read"] < snap1["bytes_read"], (
         f"superstep mode should read strictly less: "
         f"{snap4['bytes_read']} !< {snap1['bytes_read']}")
+
+
+def test_fused_hops_refused_where_kernels_compile(monkeypatch):
+    """Where kernels compile instead of interpreting (a TPU), the fused
+    layer-0 kernel is refused at build, naming it, rather than replaced by
+    another path; csd supersteps need no kernel and still build."""
+    from repro.api import IndexSpec, SearchService
+    from repro.kernels import ops
+    vecs = np.asarray(np.random.default_rng(0).normal(size=(64, 8)),
+                      np.float32)
+    monkeypatch.setattr(ops, "default_interpret", lambda: False)
+    cfg = hg.HNSWConfig(M=4, ef_construction=16, seed=0)
+    for backend in ("hnsw", "partitioned"):
+        with pytest.raises(NotImplementedError,
+                           match="fused_traversal_pallas"):
+            SearchService.build(vecs, IndexSpec(backend=backend,
+                                                fused_hops=4, hnsw=cfg))
+    SearchService.build(vecs, IndexSpec(backend="partitioned", hnsw=cfg))
