@@ -1,0 +1,10 @@
+"""`device.idle_share` in the csd cells, where it moves `qps.csd`: read as
+device.idle_share.py reads it."""
+
+import os
+import sys
+
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+from _alias import reader  # noqa: E402
+
+read = reader("device.idle_share")

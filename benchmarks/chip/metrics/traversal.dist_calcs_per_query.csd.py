@@ -1,0 +1,10 @@
+"""`traversal.dist_calcs_per_query` in the csd cells, where it moves `qps.csd`: read as
+traversal.dist_calcs_per_query.py reads it."""
+
+import os
+import sys
+
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+from _alias import reader  # noqa: E402
+
+read = reader("traversal.dist_calcs_per_query")
