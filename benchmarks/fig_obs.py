@@ -6,7 +6,7 @@ Two questions, both acceptance bounds of the obs subsystem (ISSUE 7):
     fig_cluster drives the csd backend with tracing disabled (twice —
     the second run measures run-to-run noise, which is the bar "disabled
     is unmeasurable" must clear), with ONLY the continuous profiler on
-    (the always-on production posture, budgeted < 2 % — ISSUE 10),
+    (switched on for calibration; budgeted < 2 %),
     fully sampled (target < 5 % QPS loss), and at 10 % sampling;
   * where does a request's time GO? A traced run through the full async
     serving stack (SearchServer -> batcher -> replica pool -> csd) is
@@ -54,8 +54,7 @@ def _overhead_sweep(svc, queries) -> dict:
 
     `profiled` is the continuous profiler ALONE (tracing off — the hot
     path takes the disabled-tracer branch, which hands spans to the
-    profiler instead of the no-op): the always-on production posture,
-    budgeted at < 2 % QPS loss."""
+    profiler instead of the no-op), budgeted at < 2 % QPS loss."""
     out = {}
     states = [
         ("baseline", dict(enabled=False), False),
@@ -72,7 +71,7 @@ def _overhead_sweep(svc, queries) -> dict:
         out[name] = _throughput(svc.search, queries)
     TRACER.configure(enabled=False)
     TRACER.clear()
-    PROFILER.configure(enabled=True)                # production default
+    PROFILER.configure(enabled=False)               # the default
     PROFILER.reset()
     base = out["baseline"]["qps"]
     for name in ("disabled", "profiled", "sampled_1.0", "sampled_0.1"):
@@ -111,6 +110,7 @@ def _stage_breakdown(svc, queries) -> dict:
     # the continuous profiler saw the same traffic through its Tracer hook;
     # its live attribution must agree with the post-hoc span analysis below
     live = profile_report(reset=True)
+    PROFILER.configure(enabled=False)
     TRACER.configure(enabled=False)
     TRACER.clear()
 
@@ -181,7 +181,7 @@ def run():
     record["overhead"] = _overhead_sweep(svc, queries)
     record["stages"] = _stage_breakdown(svc, queries)
 
-    # acceptance bound (ISSUE 10): the always-on profiler must cost < 2 %
+    # acceptance bound: the profiler, on, must cost < 2 %
     # QPS — checked BEFORE the record is written so a blown budget can
     # never land in BENCH_obs.json as a quiet regression
     prof_pct = record["overhead"]["profiled"]["overhead_pct"]

@@ -287,27 +287,33 @@ def _search_layer0(db: DeviceDB, q, qsq, ep, ep_d, p: SearchParams,
 
     def body(s):
         cand_d, cand_i, fin_d, fin_i, visited, hops, calcs = s
-        c = cand_i[0]
-        # pop: shift the sorted array left (line 3).
-        cand_d = jnp.roll(cand_d, -1).at[-1].set(jnp.inf)
-        cand_i = jnp.roll(cand_i, -1).at[-1].set(-1)
-
-        nbrs = db.l0_nbrs[c]                       # [maxM0_pad]
-        valid = nbrs >= 0
-        was, visited = visited_test_and_set(visited, jnp.where(valid, nbrs, 0), valid)
-        active = valid & ~was
-        d, safe = _batch_distances(db, q, qsq, nbrs, active, p.metric, lut)
-        calcs = calcs + jnp.sum(active)
-        # line 11 guard: only candidates that can enter the final list.
-        d = jnp.where(d < fin_d[-1], d, jnp.inf)
-        ids = jnp.where(jnp.isfinite(d), safe, -1)
-        order = jnp.argsort(d, stable=True)
-        bd, bi = d[order], ids[order]
-
-        fd, fi = merge_sorted(fin_d, fin_i, bd, bi)
-        fin_d, fin_i = fd[:EF], fi[:EF]
-        cd, ci = merge_sorted(cand_d, cand_i, bd, bi)
-        cand_d, cand_i = cd[:C], ci[:C]
+        # named scopes label the hop's stages in the compiled program's op
+        # metadata (profiler traces); they change no operation
+        with jax.named_scope("expand"):
+            c = cand_i[0]
+            # pop: shift the sorted array left (line 3).
+            cand_d = jnp.roll(cand_d, -1).at[-1].set(jnp.inf)
+            cand_i = jnp.roll(cand_i, -1).at[-1].set(-1)
+            nbrs = db.l0_nbrs[c]                   # [maxM0_pad]
+            valid = nbrs >= 0
+        with jax.named_scope("visited"):
+            was, visited = visited_test_and_set(
+                visited, jnp.where(valid, nbrs, 0), valid)
+            active = valid & ~was
+        with jax.named_scope("distances"):
+            d, safe = _batch_distances(db, q, qsq, nbrs, active, p.metric,
+                                       lut)
+            calcs = calcs + jnp.sum(active)
+        with jax.named_scope("merge"):
+            # line 11 guard: only candidates that can enter the final list.
+            d = jnp.where(d < fin_d[-1], d, jnp.inf)
+            ids = jnp.where(jnp.isfinite(d), safe, -1)
+            order = jnp.argsort(d, stable=True)
+            bd, bi = d[order], ids[order]
+            fd, fi = merge_sorted(fin_d, fin_i, bd, bi)
+            fin_d, fin_i = fd[:EF], fi[:EF]
+            cd, ci = merge_sorted(cand_d, cand_i, bd, bi)
+            cand_d, cand_i = cd[:C], ci[:C]
         return cand_d, cand_i, fin_d, fin_i, visited, hops + 1, calcs
 
     cand_d, cand_i, fin_d, fin_i, visited, hops, calcs = jax.lax.while_loop(

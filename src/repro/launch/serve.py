@@ -187,7 +187,8 @@ def main(argv=None):
                          "(implies --trace)")
     ap.add_argument("--metrics-out", default=None,
                     help="write a metrics snapshot here (.json -> JSON, "
-                         "else Prometheus text exposition)")
+                         "else Prometheus text exposition); turns the "
+                         "continuous profiler on")
     ap.add_argument("--metrics-interval", type=float, default=0.0,
                     help="with --metrics-out: re-emit the file every N "
                          "seconds while serving (0 = once, at the end)")
@@ -206,9 +207,13 @@ def main(argv=None):
 
     from repro.launch.compile_cache import enable_compile_cache
     enable_compile_cache()
-    from repro.obs import PeriodicExporter, TRACER, write_snapshot
+    from repro.obs import PROFILER, PeriodicExporter, TRACER, write_snapshot
     if args.trace or args.trace_out:
         TRACER.configure(enabled=True, sample_rate=args.trace_sample)
+    if args.metrics_out:
+        # the snapshot carries the per-stage times `ann_dryrun
+        # --calibrated` fits from; the profiler is off by default
+        PROFILER.configure(enabled=True)
 
     slo_tracker = None
     if args.slo:

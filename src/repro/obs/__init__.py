@@ -7,15 +7,18 @@ percentiles, the PageCache counter dicts):
 
   * `TRACER`   — hierarchical trace spans over the whole request path
                  (request -> queue -> batch -> dispatch -> shard ->
-                 segment -> traversal -> store-read -> hop), exported as
-                 Chrome/Perfetto trace-event JSON. Near-zero cost when
+                 segment -> traversal -> hop -> store-read /
+                 device-wait), exported as Chrome/Perfetto trace-event
+                 JSON and, while enabled, as `jax.profiler` annotations
+                 on the device trace's clock. One attribute check when
                  disabled (the default), sampled when enabled.
   * `REGISTRY` — process-wide metrics (counters / gauges / bounded
                  histograms) every layer publishes into, snapshot behind
                  one call, exported as Prometheus text or JSON.
   * `PROFILER` — continuous per-stage profiling fed at span close,
-                 always on (tracing on OR off); `profile_report()` is
-                 the fig_obs latency attribution, live.
+                 off by default; switched on, it runs with tracing on
+                 or off; `profile_report()` is the fig_obs latency
+                 attribution, live.
   * `SLOTracker` / `default_slos` — declarative latency / error-rate /
                  recall objectives with multi-window burn-rate breaches.
   * `FlightRecorder` — bounded capture of the N slowest + errored
@@ -42,8 +45,8 @@ from repro.obs.stats import latency_summary
 from repro.obs.trace import TRACER, SpanCtx, Tracer
 
 # Close the loop: the global tracer feeds the global profiler at span
-# close, so per-stage timings keep flowing with tracing disabled (the
-# production default). Private Tracer()/Profiler() instances stay
+# close, so once the profiler is switched on per-stage timings flow with
+# tracing disabled too. Private Tracer()/Profiler() instances stay
 # unlinked — tests rely on the disabled tracer's shared no-op span.
 TRACER.profiler = PROFILER
 

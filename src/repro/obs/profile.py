@@ -4,11 +4,14 @@ fig_obs answers "where does a request's time go?" by replaying recorded
 trace spans offline. This module answers it continuously, in-process,
 with the same zero-cost-when-disabled discipline as `Tracer`:
 
-  * every span close feeds `PROFILER.observe(name, ms)` — either through
-    `Tracer._record` (tracing enabled) or through the lightweight
+  * off by default: `PROFILER.configure(enabled=True)` switches it on
+    (`repro.obs.calibrate`, `scripts/slo_smoke.py`, `benchmarks/fig_obs.py`
+    do); off, the tracer's disabled path hands out the shared no-op;
+  * on, every span close feeds `PROFILER.observe(name, ms)` — either
+    through `Tracer._record` (tracing enabled) or through the lightweight
     `_ProfSpan` the tracer hands out on its disabled path (tracing
-    disabled, the default), so stage timings flow whether or not trace
-    events are being retained;
+    disabled), so stage timings flow whether or not trace events are
+    being retained;
   * durations aggregate into REGISTRY histograms
     (`profile_stage_ms{stage=...}`) — bounded memory, Prometheus-ready —
     plus internal resettable sums that `profile_report()` turns into the
@@ -28,12 +31,12 @@ with the same zero-cost-when-disabled discipline as `Tracer`:
 Attribution caveat: with tracing enabled at sample_rate < 1.0, stage
 spans are only observed for sampled traces while `request()` sees every
 request — the breakdown then under-attributes stages. It is exact when
-tracing is off (the production default) or fully sampled.
+tracing is off or fully sampled.
 
-Overhead budget: the always-on profiler must cost <= 2% QPS on the csd
-lane harness (asserted by benchmarks/fig_obs.py before BENCH_obs.json is
-written). Disabled, it is one attribute check on the tracer's disabled
-path.
+Overhead budget: switched on, the profiler must cost <= 2% QPS on the
+csd lane harness (asserted by benchmarks/fig_obs.py before
+BENCH_obs.json is written). Disabled, it is one attribute check on the
+tracer's disabled path.
 """
 
 from __future__ import annotations
@@ -125,10 +128,10 @@ def _collect_profiler(prof: "Profiler"):
 class Profiler:
     """Process-wide per-stage duration aggregator (one instance: PROFILER).
 
-    Enabled by default — "always-on" is the point; `configure(
-    enabled=False)` reduces it to one attribute check per span."""
+    Off by default; `configure(enabled=True)` switches it on. Off, it is
+    one attribute check per span."""
 
-    def __init__(self, enabled: bool = True,
+    def __init__(self, enabled: bool = False,
                  registry: MetricsRegistry = REGISTRY):
         self.enabled = bool(enabled)
         self.registry = registry
@@ -247,8 +250,8 @@ class Profiler:
 
 
 # The process-wide profiler (attached to TRACER by repro.obs.__init__).
-# Enabled by default: continuous profiling is the always-on telemetry tier.
-PROFILER = Profiler(enabled=True)
+# Off by default: the hot path's spans then cost one attribute check.
+PROFILER = Profiler()
 
 
 def profile_report(reset: bool = False) -> dict:

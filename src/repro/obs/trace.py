@@ -17,7 +17,14 @@ disabled overhead budget):
 
   * disabled        : `span()` is one attribute check returning a shared
                       no-op context manager — no allocation, no clock read,
-                      no lock. This is the default state.
+                      no lock, no JAX call. This is the default state.
+  * device clock    : every live span (`span`/`child_span`) also opens a
+                      `jax.profiler.TraceAnnotation` of its name on its
+                      own thread, so under `jax.profiler.start_trace` the
+                      program's spans land on the host threads of the same
+                      `.xplane.pb` as the device operations, on one clock.
+                      Retroactive spans (`record_span`) stay in memory and
+                      in the Chrome export only.
   * sampling        : the decision is made ONCE per trace, at the root
                       span (`sample_rate`); descendants inherit it through
                       a thread-local span stack, so an unsampled request
@@ -35,6 +42,8 @@ disabled overhead budget):
 Span identity is exported into each trace event's `args` (`span_id`,
 `parent_id`, `trace_id`) so tests and the per-stage benchmark can rebuild
 the tree; Chrome/Perfetto nest visually by (tid, time containment).
+`self_times` turns recorded spans into each name's self time over a
+window: the per-layer shares the chip benchmark reports.
 """
 
 from __future__ import annotations
@@ -44,7 +53,9 @@ import random
 import threading
 import time
 
-__all__ = ["SpanCtx", "Tracer", "TRACER"]
+from jax.profiler import TraceAnnotation
+
+__all__ = ["SpanCtx", "Tracer", "TRACER", "self_times"]
 
 
 class SpanCtx:
@@ -113,10 +124,11 @@ class _UnsampledSpan:
 
 
 class Span:
-    """One live sampled span; records itself on exit."""
+    """One live sampled span; records itself on exit. While open it also
+    holds a profiler annotation of its name (the device trace's clock)."""
 
     __slots__ = ("_tracer", "_stack", "name", "attrs", "trace_id",
-                 "span_id", "parent_id", "t0", "t1")
+                 "span_id", "parent_id", "t0", "t1", "_mark")
     sampled = True
 
     def __init__(self, tracer: "Tracer", stack: list, name: str,
@@ -130,6 +142,7 @@ class Span:
         self.parent_id = parent_id
         self.t0 = 0.0
         self.t1 = 0.0
+        self._mark = None
 
     @property
     def ctx(self) -> SpanCtx:
@@ -140,11 +153,14 @@ class Span:
 
     def __enter__(self) -> "Span":
         self._stack.append(self)
+        self._mark = TraceAnnotation(self.name)
+        self._mark.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         self.t1 = time.perf_counter()
+        self._mark.__exit__(None, None, None)
         self._stack.pop()
         self._tracer._record(self.name, self.t0, self.t1, self.trace_id,
                              self.span_id, self.parent_id, None, self.attrs)
@@ -172,9 +188,10 @@ class Tracer:
         self.dropped = 0
         # Optional continuous profiler fed at span close (obs phase 2).
         # Left None on private tracers; repro.obs.__init__ attaches the
-        # global PROFILER to the global TRACER so stage timings keep
-        # flowing even with tracing disabled (span() hands out a
-        # lightweight profiler span instead of the shared no-op).
+        # global PROFILER to the global TRACER. The profiler is off by
+        # default; switched on, stage timings flow with tracing disabled
+        # too (span() hands out a lightweight profiler span instead of
+        # the shared no-op).
         self.profiler = None
 
     # -- configuration -------------------------------------------------------
@@ -391,6 +408,43 @@ class Tracer:
         with open(path, "w") as f:
             json.dump(self.export(trace_ids), f)
         return path
+
+
+def _covered(intervals) -> float:
+    """Length of the union of (start, end) intervals."""
+    total, end = 0.0, None
+    for a, b in sorted(intervals):
+        if b <= a:
+            continue
+        if end is None or a > end:
+            total += b - a
+            end = b
+        elif b > end:
+            total += b - end
+            end = b
+    return total
+
+
+def self_times(spans, lo: float, hi: float) -> dict[str, float]:
+    """Seconds of self time per span name inside the window [lo, hi].
+
+    `spans` are recorded spans (`Tracer.spans()`, any subset of them). A
+    span's self time is its time in the window less the part of it that
+    its children among `spans` cover (children by `parent` id, clipped to
+    the parent). Over one thread's properly nested spans the self times
+    add up to the time that thread spent in any span."""
+    kids: dict[int, list] = {}
+    for s in spans:
+        kids.setdefault(s["parent"], []).append(s)
+    out: dict[str, float] = {}
+    for s in spans:
+        a, b = max(s["t0"], lo), min(s["t1"], hi)
+        if b <= a:
+            continue
+        inner = _covered((max(c["t0"], a), min(c["t1"], b))
+                         for c in kids.get(s["id"], ()))
+        out[s["name"]] = out.get(s["name"], 0.0) + (b - a) - inner
+    return out
 
 
 # The process-wide tracer every layer records into. Disabled by default;

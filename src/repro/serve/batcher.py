@@ -160,43 +160,51 @@ class DynamicBatcher:
 
     def _scatter(self, batch: list[PendingQuery], resp,
                  batch_ctx=None) -> None:
-        ids = np.asarray(resp.ids)
-        dists = np.asarray(resp.dists)
-        t_done = time.perf_counter()
-        head = batch[0]
+        # on the thread that finished the batch (the replica's, when the
+        # pool's future completes there): results to host, futures resolved
         if batch_ctx is not None:
-            # retroactive: the batch window (flush -> results back), one
-            # span per batch on a virtual "batch" lane
-            TRACER.record_span("batch", head.t_dispatch, t_done,
-                               ctx=batch_ctx, tid="batch",
-                               size=len(batch), ef=head.ef)
-        for i, p in enumerate(batch):
-            stats = None
-            if p.with_stats and resp.stats is not None:
-                stats = slice_stats(resp.stats, i)
-            res = QueryResult(ids=ids[i, :p.k], dists=dists[i, :p.k],
-                              stats=stats,
-                              queue_ms=(p.t_dispatch - p.t_enqueue) * 1e3,
-                              exec_ms=(t_done - p.t_dispatch) * 1e3,
-                              e2e_ms=(t_done - p.t_enqueue) * 1e3)
-            if p.trace is not None and p.trace.sampled:
-                # retroactive per-request spans, on a virtual per-request
-                # lane so Perfetto nests request > queue/exec by containment
-                lane = f"req-{p.seq % 16}"
-                TRACER.record_span("request", p.t_enqueue, t_done,
-                                   ctx=p.trace, tid=lane, seq=p.seq, k=p.k)
-                TRACER.record_span("queue", p.t_enqueue, p.t_dispatch,
-                                   parent=p.trace, tid=lane)
-                TRACER.record_span("exec", p.t_dispatch, t_done,
-                                   parent=p.trace, tid=lane)
-            if self.collector is not None:
-                self.collector.record_done(res, t_done)
-            if self.flight is not None:
-                self.flight.record(seq=p.seq, e2e_ms=res.e2e_ms,
-                                   queue_ms=res.queue_ms,
-                                   exec_ms=res.exec_ms, k=p.k, ef=head.ef,
-                                   trace=p.trace, stats=stats)
-            p.future.set_result(res)
+            sp = TRACER.span("scatter", parent=batch_ctx, size=len(batch))
+        else:
+            sp = TRACER.child_span("scatter", size=len(batch))
+        with sp:
+            ids = np.asarray(resp.ids)
+            dists = np.asarray(resp.dists)
+            t_done = time.perf_counter()
+            head = batch[0]
+            if batch_ctx is not None:
+                # retroactive: the batch window (flush -> results back), one
+                # span per batch on a virtual "batch" lane
+                TRACER.record_span("batch", head.t_dispatch, t_done,
+                                   ctx=batch_ctx, tid="batch",
+                                   size=len(batch), ef=head.ef)
+            for i, p in enumerate(batch):
+                stats = None
+                if p.with_stats and resp.stats is not None:
+                    stats = slice_stats(resp.stats, i)
+                res = QueryResult(ids=ids[i, :p.k], dists=dists[i, :p.k],
+                                  stats=stats,
+                                  queue_ms=(p.t_dispatch - p.t_enqueue) * 1e3,
+                                  exec_ms=(t_done - p.t_dispatch) * 1e3,
+                                  e2e_ms=(t_done - p.t_enqueue) * 1e3)
+                if p.trace is not None and p.trace.sampled:
+                    # retroactive per-request spans, on a virtual
+                    # per-request lane so Perfetto nests request >
+                    # queue/exec by containment
+                    lane = f"req-{p.seq % 16}"
+                    TRACER.record_span("request", p.t_enqueue, t_done,
+                                       ctx=p.trace, tid=lane, seq=p.seq, k=p.k)
+                    TRACER.record_span("queue", p.t_enqueue, p.t_dispatch,
+                                       parent=p.trace, tid=lane)
+                    TRACER.record_span("exec", p.t_dispatch, t_done,
+                                       parent=p.trace, tid=lane)
+                if self.collector is not None:
+                    self.collector.record_done(res, t_done)
+                if self.flight is not None:
+                    self.flight.record(seq=p.seq, e2e_ms=res.e2e_ms,
+                                       queue_ms=res.queue_ms,
+                                       exec_ms=res.exec_ms, k=p.k, ef=head.ef,
+                                       trace=p.trace, stats=stats)
+                p.future.set_result(res)
 
     def _fail(self, batch: list[PendingQuery], exc: Exception) -> None:
         n = 0

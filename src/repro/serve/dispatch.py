@@ -53,6 +53,16 @@ class Replica:
         self._ex = ThreadPoolExecutor(
             max_workers=1, thread_name_prefix=f"serve-replica-{rid}")
 
+    def _start(self, t_submit: float, request, n_queries: int):
+        # the replica's thread takes the batch: on a traced batch, record
+        # the time it waited in this replica's executor since the pool's
+        # submit, a child of the batch on the virtual "batch" lane
+        ctx = getattr(request, "trace", None)
+        if ctx is not None:
+            TRACER.record_span("replica-wait", t_submit, time.perf_counter(),
+                               parent=ctx, tid="batch", replica=self.rid)
+        return self._search(request, n_queries)
+
     def _search(self, request, n_queries: int):
         # this runs on the replica's own thread: parent explicitly on the
         # batch ctx the batcher stamped (cross-thread handoff); no ctx ->
@@ -71,7 +81,8 @@ class Replica:
         with PROFILER.weighted(n_queries):
             with sp:
                 resp = self.service.search(request)
-                jax.block_until_ready((resp.ids, resp.dists))
+                with TRACER.child_span("device-wait"):
+                    jax.block_until_ready((resp.ids, resp.dists))
         self.busy_s += time.perf_counter() - t0
         self.batches += 1
         self.queries += n_queries
@@ -154,7 +165,8 @@ class ReplicaPool:
                       key=lambda r: (r.inflight, (r.rid - self._rr) % n))
             self._rr = (rep.rid + 1) % n
             rep.inflight += 1
-        fut = rep._ex.submit(rep._search, request, n_queries)
+        fut = rep._ex.submit(rep._start, time.perf_counter(), request,
+                             n_queries)
         fut.add_done_callback(lambda _f, r=rep: self._done(r))
         return fut
 

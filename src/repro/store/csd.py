@@ -108,21 +108,23 @@ def _upper_step(improved, c, c_d, calcs, nbrs, valid, vecs, sqs, q, qsq,
     along unused."""
     def one(improved, c, c_d, calcs, nbrs, valid, vecs, sqs, qq, qsq,
             *lut):
-        if lut:
-            d = pq_lut_distances(lut[0], vecs)
-        else:
-            d = metric_distance(metric, jnp.sum(vecs * qq, axis=-1), sqs,
-                                qsq)
-        d = jnp.where(valid, d, jnp.inf)
-        safe = jnp.where(valid, nbrs, 0)
-        j = jnp.argmin(d)
-        best_d, best = d[j], safe[j]
-        imp = best_d < c_d
-        sel = lambda n, o: jnp.where(improved, n, o)
-        return (sel(jnp.where(imp, best, c), c),
-                sel(jnp.where(imp, best_d, c_d), c_d),
-                improved & imp,
-                sel(calcs + jnp.sum(valid), calcs))
+        with jax.named_scope("distances"):
+            if lut:
+                d = pq_lut_distances(lut[0], vecs)
+            else:
+                d = metric_distance(metric, jnp.sum(vecs * qq, axis=-1),
+                                    sqs, qsq)
+            d = jnp.where(valid, d, jnp.inf)
+        with jax.named_scope("select"):
+            safe = jnp.where(valid, nbrs, 0)
+            j = jnp.argmin(d)
+            best_d, best = d[j], safe[j]
+            imp = best_d < c_d
+            sel = lambda n, o: jnp.where(improved, n, o)
+            return (sel(jnp.where(imp, best, c), c),
+                    sel(jnp.where(imp, best_d, c_d), c_d),
+                    improved & imp,
+                    sel(calcs + jnp.sum(valid), calcs))
     extra = () if lut is None else (lut,)
     return jax.vmap(one)(improved, c, c_d, calcs, nbrs, valid, vecs, sqs,
                          q, qsq, *extra)
@@ -143,31 +145,35 @@ def _layer0_step(active, cand_d, cand_i, fin_d, fin_i, hops, calcs,
 
     def one(active, cand_d, cand_i, fin_d, fin_i, hops, calcs,
             nbrs, act, vecs, sqs, qq, qsq, *lut):
-        ncand_d = jnp.roll(cand_d, -1).at[-1].set(jnp.inf)
-        ncand_i = jnp.roll(cand_i, -1).at[-1].set(-1)
-        # mul+sum matches core/search.py's _batch_distances bit for bit —
-        # see the note there on matvec reduction-order instability; the PQ
-        # branch is the one pq_lut_distances accumulation for the same
-        # reason
-        if lut:
-            d = pq_lut_distances(lut[0], vecs)
-        else:
-            d = metric_distance(metric, jnp.sum(vecs * qq, axis=-1), sqs,
-                                qsq)
-        d = jnp.where(act, d, jnp.inf)
-        ncalcs = calcs + jnp.sum(act)
-        d = jnp.where(d < fin_d[-1], d, jnp.inf)
-        safe = jnp.where(act, nbrs, 0)
-        ids = jnp.where(jnp.isfinite(d), safe, -1)
-        order = jnp.argsort(d, stable=True)
-        bd, bi = d[order], ids[order]
-        fd, fi = merge_sorted(fin_d, fin_i, bd, bi)
-        cd, ci = merge_sorted(ncand_d, ncand_i, bd, bi)
-        sel = lambda n, o: jnp.where(active, n, o)
-        return (sel(cd[:C], cand_d), sel(ci[:C], cand_i),
-                sel(fd[:EF], fin_d), sel(fi[:EF], fin_i),
-                hops + active.astype(hops.dtype),
-                sel(ncalcs, calcs))
+        # named scopes as in core/search.py's hop body (op metadata only)
+        with jax.named_scope("expand"):
+            ncand_d = jnp.roll(cand_d, -1).at[-1].set(jnp.inf)
+            ncand_i = jnp.roll(cand_i, -1).at[-1].set(-1)
+        with jax.named_scope("distances"):
+            # mul+sum matches core/search.py's _batch_distances bit for
+            # bit — see the note there on matvec reduction-order
+            # instability; the PQ branch is the one pq_lut_distances
+            # accumulation for the same reason
+            if lut:
+                d = pq_lut_distances(lut[0], vecs)
+            else:
+                d = metric_distance(metric, jnp.sum(vecs * qq, axis=-1),
+                                    sqs, qsq)
+            d = jnp.where(act, d, jnp.inf)
+            ncalcs = calcs + jnp.sum(act)
+        with jax.named_scope("merge"):
+            d = jnp.where(d < fin_d[-1], d, jnp.inf)
+            safe = jnp.where(act, nbrs, 0)
+            ids = jnp.where(jnp.isfinite(d), safe, -1)
+            order = jnp.argsort(d, stable=True)
+            bd, bi = d[order], ids[order]
+            fd, fi = merge_sorted(fin_d, fin_i, bd, bi)
+            cd, ci = merge_sorted(ncand_d, ncand_i, bd, bi)
+            sel = lambda n, o: jnp.where(active, n, o)
+            return (sel(cd[:C], cand_d), sel(ci[:C], cand_i),
+                    sel(fd[:EF], fin_d), sel(fi[:EF], fin_i),
+                    hops + active.astype(hops.dtype),
+                    sel(ncalcs, calcs))
     extra = () if lut is None else (lut,)
     return jax.vmap(one)(active, cand_d, cand_i, fin_d, fin_i, hops, calcs,
                          nbrs, act, vecs, sqs, q, qsq, *extra)
@@ -358,16 +364,16 @@ def _layer0_supersteps(reader: StoreReader, p: int, q_pad, qsq, bitmap,
     M0, D = reader.m0_pad, reader.d_pad
     C, EF = sp.cand_size, sp.ef
     metric = sp.metric
-    qh = np.asarray(q_pad, np.float32)
-    qsqh = np.asarray(qsq, np.float32)
     steps = 0
-
-    # shadow of the device beam, advanced in place by plan(); resynced
-    # from device arrays only at pipeline bubbles
-    scand_d = np.array(cand_d)
-    scand_i = np.array(cand_i)
-    sfin_d = np.array(fin_d)
-    shops = np.array(hops)
+    with TRACER.child_span("device-wait"):
+        qh = np.asarray(q_pad, np.float32)
+        qsqh = np.asarray(qsq, np.float32)
+        # shadow of the device beam, advanced in place by plan(); resynced
+        # from device arrays only at pipeline bubbles
+        scand_d = np.array(cand_d)
+        scand_i = np.array(cand_i)
+        sfin_d = np.array(fin_d)
+        shops = np.array(hops)
 
     def plan():
         """Plan up to H hops from shadow state (store reads + visited
@@ -436,10 +442,11 @@ def _layer0_supersteps(reader: StoreReader, p: int, q_pad, qsq, bitmap,
     def resync(lanes):
         """Pull true device beam state back into the shadow for `lanes`
         (boolean mask) — the only full-state host syncs in this driver."""
-        scand_d[lanes] = np.asarray(cand_d)[lanes]
-        scand_i[lanes] = np.asarray(cand_i)[lanes]
-        sfin_d[lanes] = np.asarray(fin_d)[lanes]
-        shops[lanes] = np.asarray(hops)[lanes]
+        with TRACER.child_span("device-wait"):
+            scand_d[lanes] = np.asarray(cand_d)[lanes]
+            scand_i[lanes] = np.asarray(cand_i)[lanes]
+            sfin_d[lanes] = np.asarray(fin_d)[lanes]
+            shops[lanes] = np.asarray(hops)[lanes]
 
     def settle(prev, applied_h, nxt):
         """Handle rejected speculation of the just-finished superstep
@@ -471,15 +478,18 @@ def _layer0_supersteps(reader: StoreReader, p: int, q_pad, qsq, bitmap,
         ps = plan()                  # overlaps the in-flight kernel
         if pending is not None:
             prev, applied = pending
-            applied_h = np.asarray(applied)       # sync: superstep done
+            with TRACER.child_span("device-wait"):
+                applied_h = np.asarray(applied)   # sync: superstep done
             pending = None
             if settle(prev, applied_h, ps) and ps is None:
                 ps = plan()          # resynced lanes may still be live
         if ps is None:
             # shadow says done; the device has the final word (a last-ulp
             # tie can terminate the shadow while the device beam is live)
-            live = ((np.asarray(cand_d)[:, 0] < np.asarray(fin_d)[:, -1])
-                    & (np.asarray(hops) < sp.max_hops))
+            with TRACER.child_span("device-wait"):
+                live = ((np.asarray(cand_d)[:, 0]
+                         < np.asarray(fin_d)[:, -1])
+                        & (np.asarray(hops) < sp.max_hops))
             if not live.any():
                 break
             resync(live)
@@ -541,11 +551,14 @@ def _search_one_partition(reader: StoreReader, p: int, q_pad: jnp.ndarray,
     for layer in range(min(n_layers, max_level), 0, -1):
         improved = jnp.ones((B,), bool)
         hop = 0
-        while bool(np.asarray(improved).any()) and hop < sp.upper_hops:
-            imp_h = np.asarray(improved)
-            cur_h = np.asarray(cur)
-            nbrs = np.full((B, reader.m_pad), -1, np.int32)
-            if imp_h.any():
+        while hop < sp.upper_hops:
+            with TRACER.child_span("upper-hop", layer=layer, hop=hop):
+                with TRACER.child_span("device-wait"):
+                    imp_h = np.asarray(improved)
+                    cur_h = np.asarray(cur)
+                if not imp_h.any():
+                    break
+                nbrs = np.full((B, reader.m_pad), -1, np.int32)
                 ptr = reader.read_rows(
                     "up_ptr", reader.row("up_ptr", p, cur_h[imp_h]))[:, 0]
                 has = ptr >= 0
@@ -553,23 +566,24 @@ def _search_one_partition(reader: StoreReader, p: int, q_pad: jnp.ndarray,
                     urows = reader.up_row(p, layer - 1, ptr[has])
                     lanes = np.flatnonzero(imp_h)[has]
                     nbrs[lanes] = reader.read_rows("up_nbrs", urows)
-            valid = (nbrs >= 0) & imp_h[:, None]
-            if pq:
-                vecs = _gather_codes(reader, p, nbrs, valid)
-                sqs = np.zeros(nbrs.shape, np.float32)
-            else:
-                vecs, sqs = _gather_vec_sq(reader, p, nbrs, valid)
-            cur, cur_d, improved, calcs = _upper_step(
-                improved, cur, cur_d, calcs,
-                jnp.asarray(nbrs), jnp.asarray(valid),
-                jnp.asarray(vecs), jnp.asarray(sqs), q_pad, qsq, metric,
-                lut=luts)
+                valid = (nbrs >= 0) & imp_h[:, None]
+                if pq:
+                    vecs = _gather_codes(reader, p, nbrs, valid)
+                    sqs = np.zeros(nbrs.shape, np.float32)
+                else:
+                    vecs, sqs = _gather_vec_sq(reader, p, nbrs, valid)
+                cur, cur_d, improved, calcs = _upper_step(
+                    improved, cur, cur_d, calcs,
+                    jnp.asarray(nbrs), jnp.asarray(valid),
+                    jnp.asarray(vecs), jnp.asarray(sqs), q_pad, qsq, metric,
+                    lut=luts)
             hop += 1
 
     # -- layer 0: lockstep beam search (paper §5.2.3) -----------------------
     n_words = bitmap_words(reader.n_pad)
     bitmap = np.zeros((B, n_words), np.uint32)
-    ep_ids = np.asarray(cur)[:, None]
+    with TRACER.child_span("device-wait"):
+        ep_ids = np.asarray(cur)[:, None]
     _visited_test_and_set(bitmap, ep_ids, np.ones((B, 1), bool))
     cand_d = jnp.full((B, C), jnp.inf).at[:, 0].set(cur_d)
     cand_i = jnp.full((B, C), -1, jnp.int32).at[:, 0].set(cur)
@@ -589,14 +603,16 @@ def _search_one_partition(reader: StoreReader, p: int, q_pad: jnp.ndarray,
     else:
         hop_no = 0
         while True:
-            cd_h, fd_h = np.asarray(cand_d), np.asarray(fin_d)
-            hops_h = np.asarray(hops)
+            with TRACER.child_span("device-wait"):
+                cd_h, fd_h = np.asarray(cand_d), np.asarray(fin_d)
+                hops_h = np.asarray(hops)
             active = (cd_h[:, 0] < fd_h[:, -1]) & (hops_h < sp.max_hops)
             if not active.any():
                 break
             with TRACER.child_span("hop", hop=hop_no,
                                    active=int(active.sum())):
-                pops = np.asarray(cand_i)[:, 0]
+                with TRACER.child_span("device-wait"):
+                    pops = np.asarray(cand_i)[:, 0]
                 nbrs = np.full((B, reader.m0_pad), -1, np.int32)
                 if active.any():
                     lanes = np.flatnonzero(active)
@@ -610,28 +626,33 @@ def _search_one_partition(reader: StoreReader, p: int, q_pad: jnp.ndarray,
                     sqs = np.zeros(nbrs.shape, np.float32)
                 else:
                     vecs, sqs = _gather_vec_sq(reader, p, nbrs, act)
-                # hop-kernel covers only the jitted dispatch — the async
-                # device compute itself overlaps the next hop's host work by
-                # design, so the span is the submit cost, not the device time
+                # hop-kernel covers only the jitted dispatch (the submit
+                # cost); the kernel's device time shows up as the
+                # device-wait of the prefetch sync that follows
                 with TRACER.child_span("hop-kernel"):
                     cand_d, cand_i, fin_d, fin_i, hops, calcs = _layer0_step(
                         jnp.asarray(active), cand_d, cand_i, fin_d, fin_i,
                         hops, calcs, jnp.asarray(nbrs), jnp.asarray(act),
                         jnp.asarray(vecs), jnp.asarray(sqs), q_pad, qsq,
                         metric, lut=luts)
-                # overlap the next hop's fetches with this round-trip
-                reader.prefetch_next_hop(p, np.asarray(cand_i)[:, :2])
+                # overlap the next hop's fetches with this round-trip; the
+                # candidates are this hop's kernel output, so this waits on it
+                with TRACER.child_span("device-wait"):
+                    nxt = np.asarray(cand_i)[:, :2]
+                reader.prefetch_next_hop(p, nxt)
             hop_no += 1
         steps = hop_no
 
-    k_i = np.asarray(fin_i)[:, :K]
-    k_d = np.asarray(fin_d)[:, :K]
+    with TRACER.child_span("device-wait"):
+        k_i = np.asarray(fin_i)[:, :K]
+        k_d = np.asarray(fin_d)[:, :K]
+        hops_h, calcs_h = np.asarray(hops), np.asarray(calcs)
     k_g = np.full_like(k_i, -1)
     vmask = k_i >= 0
     if vmask.any():
         k_g[vmask] = reader.read_rows(
             "gids", reader.row("gids", p, k_i[vmask]))[:, 0]
-    return k_g, k_d, np.asarray(hops), np.asarray(calcs), steps
+    return k_g, k_d, hops_h, calcs_h, steps
 
 
 def store_search(reader: StoreReader, queries, params: SearchParams,
@@ -656,7 +677,8 @@ def store_search(reader: StoreReader, queries, params: SearchParams,
     if pq_quant is not None:
         luts = build_pq_lut(jnp.asarray(q),
                             jnp.asarray(pq_quant.codebooks))
-        lut_h = np.asarray(luts)      # shadow planner's prediction twin
+        with TRACER.child_span("device-wait"):
+            lut_h = np.asarray(luts)  # shadow planner's prediction twin
     elif q.shape[-1] < reader.d_pad:
         q = np.pad(q, ((0, 0), (0, reader.d_pad - q.shape[-1])))
     q_pad = jnp.asarray(q)
@@ -902,7 +924,8 @@ class CSDBackend:
             q = self.quant.decode(q)     # code-valued queries -> f32 values
         ids_c, dists = batched_rerank(vecs, sqs, q, jnp.asarray(compact), k,
                                       self.spec.metric)
-        ids_c = np.asarray(ids_c)
+        with TRACER.child_span("device-wait"):
+            ids_c = np.asarray(ids_c)
         ids = np.where(ids_c >= 0, uniq[np.maximum(ids_c, 0)], -1)
         return ids.astype(np.int32), dists
 

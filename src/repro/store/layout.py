@@ -119,36 +119,39 @@ class StoreReader:
         Duplicate rows inside one request are fetched once (the engine
         batches a whole hop's gathers into one call — the paper's wide
         block read)."""
-        t = self.blockfile.tables[table]
-        idx = np.asarray(rows, np.int64)
-        flat = idx.ravel()
-        dtype = np.dtype(t["dtype"])
-        cols, bs = t["cols"], self.block_size
-        uniq, inv = np.unique(flat, return_inverse=True)
-        need = self.blocks_of_rows(table, uniq)
+        # the whole read: block lookup, block copies and row assembly.
         # child_span: only records under an already-sampled span on this
         # thread — prefetcher-worker calls (and untraced callers) stay free.
-        with TRACER.child_span("store-read", table=table, rows=len(uniq),
-                               blocks=len(need)):
+        with TRACER.child_span("store-read", table=table) as sp:
+            t = self.blockfile.tables[table]
+            idx = np.asarray(rows, np.int64)
+            flat = idx.ravel()
+            dtype = np.dtype(t["dtype"])
+            cols, bs = t["cols"], self.block_size
+            uniq, inv = np.unique(flat, return_inverse=True)
+            need = self.blocks_of_rows(table, uniq)
+            misses = self.cache.misses
             if _get is None:
                 blocks = self.cache.get_many(need)
             else:
                 blocks = {b: _get(b) for b in need}
-        out = np.empty((len(uniq), cols), dtype)
-        for j, r in enumerate(uniq):
-            start, end = self.blockfile.row_span(table, int(r))
-            b0, b1 = start // bs, (end - 1) // bs
-            if b0 == b1:
-                buf = blocks[b0][start - b0 * bs:end - b0 * bs]
-            else:
-                parts = []
-                for b in range(b0, b1 + 1):
-                    lo = max(start, b * bs) - b * bs
-                    hi = min(end, (b + 1) * bs) - b * bs
-                    parts.append(blocks[b][lo:hi])
-                buf = b"".join(parts)
-            out[j] = np.frombuffer(buf, dtype)
-        return out[inv].reshape(idx.shape + (cols,))
+            sp.set(rows=len(uniq), blocks=len(need),
+                   misses=self.cache.misses - misses)
+            out = np.empty((len(uniq), cols), dtype)
+            for j, r in enumerate(uniq):
+                start, end = self.blockfile.row_span(table, int(r))
+                b0, b1 = start // bs, (end - 1) // bs
+                if b0 == b1:
+                    buf = blocks[b0][start - b0 * bs:end - b0 * bs]
+                else:
+                    parts = []
+                    for b in range(b0, b1 + 1):
+                        lo = max(start, b * bs) - b * bs
+                        hi = min(end, (b + 1) * bs) - b * bs
+                        parts.append(blocks[b][lo:hi])
+                    buf = b"".join(parts)
+                out[j] = np.frombuffer(buf, dtype)
+            return out[inv].reshape(idx.shape + (cols,))
 
     # -- prefetch hooks ------------------------------------------------------
 

@@ -140,3 +140,20 @@ def built_graph(small_dataset):
     cfg = hg.HNSWConfig(M=12, ef_construction=80, seed=0)
     g = hg.build_hnsw(small_dataset["vectors"], cfg)
     return g, cfg
+
+
+@pytest.fixture(autouse=True)
+def _obs_state_restored():
+    """Put the process-wide tracer's and profiler's configuration back
+    after every test and clear what they recorded. The chip benchmark's
+    span readers turn the tracer on when imported, and an enabled tracer
+    would otherwise leak into the other test files of the same worker."""
+    from repro.obs import PROFILER, TRACER
+    saved = (TRACER.enabled, TRACER.sample_rate, TRACER.max_events,
+             PROFILER.enabled)
+    yield
+    TRACER.configure(enabled=saved[0], sample_rate=saved[1],
+                     max_events=saved[2])
+    PROFILER.configure(enabled=saved[3])
+    TRACER.clear()
+    PROFILER.reset()
