@@ -8,10 +8,11 @@ One store = one directory:
                                 readable (same contract as repro.checkpoint)
 
 The unit of I/O is the *block* (default 4 KiB — the paper's flash page):
-`BlockFile.read_block` returns exactly one block and is the only way data
-leaves the file, so counting calls == counting flash reads / P2P-DMA
-transfers. Tables are fixed-stride row arrays (paper Fig. 5); each table
-region starts on a block boundary so a row's blocks are computable from its
+`BlockFile.read_block` returns exactly one block and `read_blocks(idxs)`
+one block per index; they are the only way data leaves the file, so
+counting blocks returned == counting flash reads / P2P-DMA transfers.
+Tables are fixed-stride row arrays (paper Fig. 5); each table region
+starts on a block boundary so a row's blocks are computable from its
 index alone — the "one access per point" property carried to storage.
 """
 
@@ -113,7 +114,8 @@ class BlockFileWriter:
 class BlockFile:
     """Read side: memory-mapped, strictly block-granular access.
 
-    `read_block(i)` is one emulated flash read. Nothing else reads the data
+    `read_block(i)` is one emulated flash read and `read_blocks(idxs)` is
+    `len(idxs)` of them, gathered in one go. Nothing else reads the data
     file, so callers (the PageCache) fully account the storage traffic.
     """
 
@@ -140,24 +142,32 @@ class BlockFile:
                 f"store at {path!r}: data file is "
                 f"{os.path.getsize(data)} bytes, manifest expects {expect}")
         self._mm = np.memmap(data, dtype=np.uint8, mode="r")
+        # [num_blocks, block_size] plain-ndarray view of the map: a block is
+        # a row, so a batch of blocks is one fancy-index gather
+        self._blocks = np.asarray(self._mm)[:expect].reshape(
+            self.num_blocks, self.block_size)
 
     def read_block(self, idx: int) -> bytes:
         """One flash read: returns exactly one block."""
         if not 0 <= idx < self.num_blocks:
             raise IndexError(f"block {idx} out of range [0, {self.num_blocks})")
-        lo = idx * self.block_size
-        return self._mm[lo:lo + self.block_size].tobytes()
+        return self._blocks[idx].tobytes()
 
-    def row_span(self, table: str, row: int) -> tuple[int, int]:
-        """[start, end) byte span of one table row — the single source of
-        row-addressing truth; every reader derives blocks and slices from
-        it so layout changes cannot desynchronize fetch and decode."""
+    def read_blocks(self, idxs) -> list[bytes]:
+        """`len(idxs)` flash reads in one gather from the map: one `bytes`
+        per block, in `idxs` order (separate objects, so a cache that keeps
+        some of them holds exactly the bytes it accounts)."""
+        idx = np.asarray(idxs, np.int64)
+        if idx.size and not (0 <= idx.min() and idx.max() < self.num_blocks):
+            bad = idx[(idx < 0) | (idx >= self.num_blocks)][0]
+            raise IndexError(f"block {bad} out of range [0, {self.num_blocks})")
+        return [b.tobytes() for b in self._blocks[idx]]
+
+    def row_starts(self, table: str, rows) -> np.ndarray:
+        """Byte start of every row of `rows` in `table`, as int64: the table
+        region's offset plus row x `row_bytes` (each row ends `row_bytes`
+        later). The single source of row-addressing truth; every reader
+        derives blocks and slices from it, so layout changes cannot
+        desynchronize fetch and decode."""
         t = self.tables[table]
-        start = t["offset"] + row * t["row_bytes"]
-        return start, start + t["row_bytes"]
-
-    def blocks_of_row(self, table: str, row: int) -> range:
-        """Block indices a given table row spans."""
-        start, end = self.row_span(table, row)
-        return range(start // self.block_size,
-                     (end - 1) // self.block_size + 1)
+        return t["offset"] + np.asarray(rows, np.int64) * t["row_bytes"]

@@ -1,9 +1,9 @@
 """LRU page cache in front of the block file (the SmartSSD DRAM tier).
 
 Every demand access is a hit or a miss; every miss (and every prefetch) is
-one `BlockFile.read_block` call — the emulated flash read. The counters are
-the repo's stand-in for the paper's "number of vector reads" / P2P-DMA
-traffic (Fig. 9):
+one block returned by `BlockFile.read_block` or `read_blocks` — the
+emulated flash read. The counters are the repo's stand-in for the paper's
+"number of vector reads" / P2P-DMA traffic (Fig. 9):
 
     hits, misses      demand accesses served from / missing the cache
     prefetch_reads    blocks pulled in by the Prefetcher thread
@@ -12,6 +12,9 @@ traffic (Fig. 9):
     block_reads       misses + prefetch_reads == total flash block transfers
     bytes_read        block_reads * block_size
     evictions         LRU evictions
+    fallbacks         blocks of a batched `get_many` that took the
+                      single-block `get` (evicted mid-batch, or in flight
+                      on another thread)
     peak_bytes        high-water mark of resident cached bytes — the bound
                       the out-of-core guarantee is measured against
 
@@ -23,6 +26,7 @@ from __future__ import annotations
 
 import threading
 from collections import OrderedDict
+from contextlib import contextmanager
 
 from repro.obs.metrics import REGISTRY, next_uid
 from repro.store.blockfile import BlockFile
@@ -63,12 +67,14 @@ class PageCache:
         self.uid = next_uid()
         self._lru: OrderedDict[int, bytes] = OrderedDict()
         self._inflight: dict[int, threading.Event] = {}
+        self._batches: set[threading.Event] = set()   # demand batches' claims
         self._lock = threading.Lock()
         self.hits = 0
         self.misses = 0
         self.prefetch_reads = 0
         self.prefetch_hits = 0
         self.evictions = 0
+        self.fallbacks = 0
         self.current_bytes = 0
         self.peak_bytes = 0
         REGISTRY.register_collector(self, _collect_cache)
@@ -81,6 +87,12 @@ class PageCache:
         The miss path claims the block in `_inflight` before reading, so a
         racing prefetch of the same block becomes a no-op — each block
         crosses the flash interface exactly once per residency."""
+        return self._get(idx, holding=False)
+
+    def _get(self, idx: int, holding: bool) -> bytes | None:
+        """`get`; a caller `holding` claims of a demand batch gets None in
+        place of waiting on another demand batch's claim (batches that
+        waited on each other would wait forever)."""
         while True:
             with self._lock:
                 data = self._lru.get(idx)
@@ -93,6 +105,8 @@ class PageCache:
                     ev = threading.Event()
                     self._inflight[idx] = ev
                     break                      # we own this read
+                if holding and ev in self._batches:
+                    return None
             # a prefetch (or another reader) owns it: wait, then re-check
             ev.wait()
             with self._lock:
@@ -115,28 +129,100 @@ class PageCache:
         return data
 
     def get_many(self, idxs) -> dict[int, bytes]:
-        """Demand-read a set of blocks; deduplicates within the request."""
-        return {i: self.get(i) for i in dict.fromkeys(idxs)}
+        """Demand-read a set of blocks; deduplicates within the request.
+
+        `get` on each block in request order (the same hits, misses,
+        evictions, peak and LRU order), batched: `_batch` claims and reads
+        every block neither resident nor in flight in one gather, and one
+        more lock pass walks the request in order — a resident block is a
+        hit and moves to the LRU's end, a claimed block is a miss inserted
+        by the same evict-before-insert rule. A block that is neither
+        (resident in the first pass but evicted by this walk, or in flight
+        on another thread) takes `get` at its turn, which waits for a read
+        in flight, and counts in `fallbacks`. The one exception keeps two
+        batches from waiting on each other: a block in flight in another
+        demand batch is left to `get` after this batch's claims are
+        released."""
+        out: dict[int, bytes] = {}
+        later: list[int] = []
+        with self._batch(idxs, demand=True) as (order, fetched):
+            k = 0
+            while k < len(order):
+                single = None
+                with self._lock:
+                    while k < len(order):
+                        i = order[k]
+                        k += 1
+                        data = fetched.get(i)
+                        if data is not None:
+                            self.misses += 1
+                            self._insert(i, data)
+                        elif (data := self._lru.get(i)) is not None:
+                            self._lru.move_to_end(i)
+                            self.hits += 1
+                        else:
+                            single = i
+                            self.fallbacks += 1
+                            break
+                        out[i] = data
+                if single is None:
+                    continue
+                data = self._get(single, holding=bool(fetched))
+                if data is None:
+                    later.append(single)
+                else:
+                    out[single] = data
+        for i in later:
+            out[i] = self.get(i)
+        return out
+
+    @contextmanager
+    def _batch(self, idxs, demand: bool):
+        """Claim every block of `idxs` that is neither resident nor in
+        flight, under one lock, and read the claimed blocks with one
+        `BlockFile.read_blocks` gather; yields (the request deduplicated
+        in order, {claimed block: bytes}) and releases the claims on exit.
+        A demand batch's claims are marked, as its owner may wait while
+        holding them."""
+        order = list(dict.fromkeys(idxs))
+        ev = threading.Event()
+        with self._lock:
+            claimed = [i for i in order
+                       if i not in self._lru and i not in self._inflight]
+            for i in claimed:
+                self._inflight[i] = ev
+            if demand and claimed:
+                self._batches.add(ev)
+        try:
+            yield order, (dict(zip(claimed,
+                                   self.blockfile.read_blocks(claimed)))
+                          if claimed else {})
+        finally:
+            with self._lock:
+                for i in claimed:
+                    self._inflight.pop(i, None)
+                self._batches.discard(ev)
+            ev.set()
 
     # -- prefetch path (called from the Prefetcher worker) -------------------
 
     def prefetch(self, idx: int) -> None:
         """Pull one block into the cache ahead of demand; no-op if resident
         or already in flight."""
-        with self._lock:
-            if idx in self._lru or idx in self._inflight:
-                return
-            ev = threading.Event()
-            self._inflight[idx] = ev
-        try:
-            data = self.blockfile.read_block(idx)
+        self.prefetch_many([idx])
+
+    def prefetch_many(self, idxs) -> None:
+        """Pull into the cache, in order, the blocks of `idxs` that are
+        neither resident nor in flight when it is called: one claim pass,
+        one gather, one insert pass. A resident block is left where it is
+        in the LRU, and one that this batch's own inserts evict is not
+        read again: a prefetch is a guess, and a second read would only
+        evict another block."""
+        with self._batch(idxs, demand=False) as (_, fetched):
             with self._lock:
-                self.prefetch_reads += 1
-                self._insert(idx, data)
-        finally:
-            with self._lock:
-                self._inflight.pop(idx, None)
-            ev.set()
+                for i, data in fetched.items():
+                    self.prefetch_reads += 1
+                    self._insert(i, data)
 
     def prefetch_get(self, idx: int) -> bytes:
         """Worker-side read: returns the block, counting any flash traffic

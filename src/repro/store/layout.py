@@ -10,6 +10,8 @@ and accounted.
 
 from __future__ import annotations
 
+import math
+
 import numpy as np
 
 from repro.core import hnsw_graph as hg
@@ -67,6 +69,22 @@ def write_store(path: str, pdb: PartitionedDB, block_size: int = 4096,
     w.finalize(meta)
 
 
+def _blocks_spanned(b0: np.ndarray, b1: np.ndarray) -> np.ndarray:
+    """Sorted unique blocks covering rows whose first and last blocks are
+    `b0` and `b1`, for rows that are sorted and unique: the rows' blocks
+    then come in non-decreasing order, so dropping repeats of the one
+    before leaves the sorted set."""
+    span = int((b1 - b0).max(initial=0)) + 1
+    if span == 1:
+        blk = b0
+    else:
+        blk = b0[:, None] + np.arange(span)
+        blk = blk[blk <= b1[:, None]]
+    keep = np.ones(len(blk), bool)
+    np.not_equal(blk[1:], blk[:-1], out=keep[1:])
+    return blk[keep]
+
+
 def jax_to_host(db: hg.DeviceDB) -> hg.DeviceDB:
     return hg.DeviceDB(*(np.asarray(x) for x in db))
 
@@ -105,11 +123,11 @@ class StoreReader:
         return np.asarray(r, np.int64) + (p * self.n_layers + layer) * self.up_pad
 
     def blocks_of_rows(self, table: str, rows) -> list[int]:
-        out: dict[int, None] = {}
-        for r in np.asarray(rows, np.int64).ravel():
-            for b in self.blockfile.blocks_of_row(table, int(r)):
-                out[b] = None
-        return list(out)
+        """Sorted unique blocks that hold any byte of `rows`."""
+        starts = self.blockfile.row_starts(table, np.unique(rows))
+        end = starts + (self.blockfile.tables[table]["row_bytes"] - 1)
+        return _blocks_spanned(starts // self.block_size,
+                               end // self.block_size).tolist()
 
     # -- reads ---------------------------------------------------------------
 
@@ -118,39 +136,39 @@ class StoreReader:
 
         Duplicate rows inside one request are fetched once (the engine
         batches a whole hop's gathers into one call — the paper's wide
-        block read)."""
+        block read). Addressing, the cache fetch and the assembly are
+        array operations over the whole request: the needed blocks are
+        joined in sorted order into one buffer, and every row is one
+        gather from it — a row that straddles blocks included, since its
+        blocks sit side by side there."""
         # the whole read: block lookup, block copies and row assembly.
         # child_span: only records under an already-sampled span on this
         # thread — prefetcher-worker calls (and untraced callers) stay free.
         with TRACER.child_span("store-read", table=table) as sp:
             t = self.blockfile.tables[table]
             idx = np.asarray(rows, np.int64)
-            flat = idx.ravel()
             dtype = np.dtype(t["dtype"])
-            cols, bs = t["cols"], self.block_size
-            uniq, inv = np.unique(flat, return_inverse=True)
-            need = self.blocks_of_rows(table, uniq)
-            misses = self.cache.misses
+            cols, rb, bs = t["cols"], t["row_bytes"], self.block_size
+            uniq, inv = np.unique(idx.ravel(), return_inverse=True)
+            starts = self.blockfile.row_starts(table, uniq)
+            b0 = starts // bs
+            need = _blocks_spanned(b0, (starts + rb - 1) // bs)
+            order = need.tolist()
+            misses, fallbacks = self.cache.misses, self.cache.fallbacks
             if _get is None:
-                blocks = self.cache.get_many(need)
+                blocks = self.cache.get_many(order)
             else:
-                blocks = {b: _get(b) for b in need}
-            sp.set(rows=len(uniq), blocks=len(need),
-                   misses=self.cache.misses - misses)
-            out = np.empty((len(uniq), cols), dtype)
-            for j, r in enumerate(uniq):
-                start, end = self.blockfile.row_span(table, int(r))
-                b0, b1 = start // bs, (end - 1) // bs
-                if b0 == b1:
-                    buf = blocks[b0][start - b0 * bs:end - b0 * bs]
-                else:
-                    parts = []
-                    for b in range(b0, b1 + 1):
-                        lo = max(start, b * bs) - b * bs
-                        hi = min(end, (b + 1) * bs) - b * bs
-                        parts.append(blocks[b][lo:hi])
-                    buf = b"".join(parts)
-                out[j] = np.frombuffer(buf, dtype)
+                blocks = {b: _get(b) for b in order}
+            sp.set(rows=len(uniq), blocks=len(order),
+                   misses=self.cache.misses - misses,
+                   fallback=self.cache.fallbacks - fallbacks)
+            # gather in units of g bytes: every row start and length, and
+            # the block size, are multiples of g, so a row is rb // g units
+            g = math.gcd(rb, bs, t["offset"])
+            buf = np.frombuffer(b"".join([blocks[b] for b in order]),
+                                np.dtype((np.void, g)))
+            first = (np.searchsorted(need, b0) * bs + starts - b0 * bs) // g
+            out = buf[first[:, None] + np.arange(rb // g)].view(dtype)
             return out[inv].reshape(idx.shape + (cols,))
 
     # -- prefetch hooks ------------------------------------------------------
@@ -165,19 +183,18 @@ class StoreReader:
         blocks those neighbors live in — all overlapped with device compute."""
         if self.prefetcher is None:
             return
-        cand = [int(c) for c in np.asarray(cand_ids).ravel() if c >= 0]
-        if not cand:
+        cand = np.asarray(cand_ids).ravel()
+        cand = cand[cand >= 0]
+        if not cand.size:
             return
         l0_blocks = self.blocks_of_rows("l0_nbrs", self.row("l0_nbrs", p, cand))
 
         def task():
-            for b in l0_blocks:
-                self.cache.prefetch(b)
+            self.cache.prefetch_many(l0_blocks)
             nbrs = self._parse_l0_rows(p, cand)
             if len(nbrs):
-                vec_rows = self.row("vectors", p, nbrs)
-                for b in self.blocks_of_rows("vectors", vec_rows):
-                    self.cache.prefetch(b)
+                self.cache.prefetch_many(self.blocks_of_rows(
+                    "vectors", self.row("vectors", p, nbrs)))
 
         self.prefetcher.submit(task)
 

@@ -46,14 +46,8 @@ class Prefetcher:
         self._q.put(fn)
 
     def prefetch_blocks(self, idxs) -> None:
-        cache = self.cache
-        blocks = list(dict.fromkeys(idxs))
-
-        def task():
-            for i in blocks:
-                cache.prefetch(i)
-
-        self._q.put(task)
+        blocks = list(idxs)
+        self._q.put(lambda: self.cache.prefetch_many(blocks))
 
     def drain(self) -> None:
         """Block until every queued task has run (tests / deterministic

@@ -115,29 +115,48 @@ def tiny_csd(tmp_path_factory):
 
 
 def test_store_read_covers_the_row_assembly(tiny_csd, monkeypatch):
-    """With each row's addressing slowed by 2 ms, the `store-read` span
-    is as long as the whole of `read_rows`, row assembly included."""
+    """With the cache fetch slowed by 2 ms a block, and each fetched block
+    slowed by 2 ms more as the assembly takes it, the `store-read` span is
+    as long as the whole of `read_rows`, row assembly included."""
+    from repro.store import open_store
     svc, _ = tiny_csd
-    reader = svc.backend.reader
-    slow = reader.blockfile.row_span
+    # a fresh, cold reader over the service's store: every block misses
+    reader = open_store(svc.spec.storage_path, 8 * 1024, prefetch=False)
+    fetch = reader.cache.get_many
 
-    def row_span(table, row):
-        time.sleep(0.002)
-        return slow(table, row)
-    monkeypatch.setattr(reader.blockfile, "row_span", row_span)
+    class SlowBlocks(dict):
+        def __getitem__(self, b):
+            time.sleep(0.002)
+            return dict.__getitem__(self, b)
+
+    def get_many(idxs):
+        idxs = list(idxs)
+        time.sleep(0.002 * len(idxs))
+        return SlowBlocks(fetch(idxs))
+    monkeypatch.setattr(reader.cache, "get_many", get_many)
     TRACER.configure(enabled=True, sample_rate=1.0)
     TRACER.clear()
+    rows = np.arange(20) * 7
     with TRACER.span("root"):
         t0 = time.perf_counter()
-        rows = reader.read_rows("vectors", np.arange(20) * 7)
+        got = reader.read_rows("vectors", rows)
         t1 = time.perf_counter()
-    assert rows.shape[0] == 20
-    sp, = [s for s in TRACER.spans() if s["name"] == "store-read"]
-    assert sp["t1"] - sp["t0"] >= 0.95 * (t1 - t0)
-    assert sp["t1"] - sp["t0"] >= 2 * 20 * 0.002   # lookup and assembly
-    assert sp["attrs"]["table"] == "vectors"
-    assert sp["attrs"]["rows"] == 20 and sp["attrs"]["blocks"] >= 1
-    assert 0 <= sp["attrs"]["misses"] <= sp["attrs"]["blocks"]
+        reader.read_rows("vectors", rows)
+    assert got.shape[0] == 20
+    first, again = [s for s in TRACER.spans() if s["name"] == "store-read"]
+    n = first["attrs"]["blocks"]
+    assert first["t1"] - first["t0"] >= 0.95 * (t1 - t0)
+    assert first["t1"] - first["t0"] >= 2 * n * 0.002   # fetch and assembly
+    assert first["attrs"]["table"] == "vectors"
+    assert first["attrs"]["rows"] == 20
+    assert first["attrs"]["misses"] == n and first["attrs"]["fallback"] == 0
+    # the 20 blocks overflow the 16-block cache: the repeat misses the four
+    # evicted first, whose inserts evict every later block before its turn
+    # in the request, so those take the single-block path
+    cap = reader.cache.capacity_bytes // reader.block_size
+    assert n > cap
+    assert again["attrs"]["misses"] == n
+    assert again["attrs"]["fallback"] == cap
 
 
 def test_csd_search_records_device_waits_under_hop_and_upper_hop(tiny_csd):
