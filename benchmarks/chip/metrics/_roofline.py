@@ -5,7 +5,9 @@ window's answers, over the window's length, is the rate of work. Time:
 the device seconds of the program's modules in the trace over the traced
 window's length is the rate at which the device spent time on them. The
 share is the least time the chip could take for the work over the time it
-took, both per second of the window. The bound that applies is noted."""
+took, both per second of the window. The bound that applies is noted.
+Nothing is read, and a note says why, where the backend does not count
+hops or distance evaluations."""
 
 import os
 import sys
@@ -19,15 +21,17 @@ def share(ctx, name: str, modules: tuple[str, ...]):
     t = ctx.trace
     if t is None or not ctx.stats["queries"]:
         return None
+    calcs = ctx.counter("dist_calcs", name)
+    hops = ctx.counter("hops", name)
+    if calcs is None or hops is None:       # the work cannot be counted
+        return None
     dev_s = sum(t["modules"][m]["seconds"] for m in modules
                 if m in t["modules"])
     if dev_s <= 0 or t["window_s"] <= 0:
         return None
-    s = ctx.stats
-    n_bytes = counts.traversal_bytes(s["dist_calcs"], s["hops"],
-                                     ctx.shape["row_bytes"],
+    n_bytes = counts.traversal_bytes(calcs, hops, ctx.shape["row_bytes"],
                                      ctx.shape["nbr_list_bytes"])
-    n_ops = counts.traversal_ops(s["dist_calcs"], ctx.shape["dim"])
+    n_ops = counts.traversal_ops(calcs, ctx.shape["dim"])
     least, bound = counts.least_time(n_bytes, n_ops, ctx.peak)
     layer_s = ctx.layer[1] - ctx.layer[0]
     ctx.note(f"{name}: bound={bound} bytes={n_bytes} ops={n_ops} "

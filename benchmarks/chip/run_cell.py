@@ -7,16 +7,20 @@
 Everything about a cell is found by name: its entry in BENCHMARK.json
 (chips, and which metrics it reports), `workloads/<cell>.json` (config,
 traffic kind and parameters, traced seconds), the
-config's file (deployment), `traffic/<kind>.py` (load generator) and
-`metrics/<metric>.py` (one reader per metric). A new cell, traffic mix or
-metric is a new file and a new entry, never an edit here.
+config's file (deployment: its `backend`, and its `replicas` over the
+cell's chips), `traffic/<kind>.py`
+(load generator) and `metrics/<metric>.py` (one reader per metric). A new
+cell, traffic mix, metric, backend choice or chip layout is a new file
+and a new entry, never an edit here.
 
-A run: refuse any platform but TPU; turn on the compile cache; make rows
-and queries (data.py; --seed orders the queries); open the index, which
-the first run in a checkout builds through `SearchService.build` and
-saves; warm the batch shapes the cell's traffic sends; drive
-`SearchServer.submit` for --seconds; then judge every answer against an
-exact scan (reference.py). With --trace 1 the
+A run: refuse a configuration the cell's chips cannot hold, any platform
+but TPU and fewer chips than the cell asks for; turn on the compile
+cache; make rows and queries (data.py; --seed orders the queries); open
+the index on the cell's chips, which the first run in a checkout builds
+through `SearchService.build` and saves; warm every replica for the
+batch shapes the cell's traffic sends; drive `SearchServer.submit` for
+--seconds; then judge every answer against an exact scan (reference.py).
+With --trace 1 the
 first `trace_seconds` of the window run under the profiler and the
 per-layer metrics are reported; with --trace 0 the end-to-end ones. The
 last line of stdout is one JSON object; the numbers compared for `correct`
@@ -268,6 +272,15 @@ class Context:
     def note(self, msg: str) -> None:
         self.notes.append(msg)
 
+    def counter(self, key: str, metric: str):
+        """`stats[key]`; None, with a note, where the backend does not
+        count `key` (its QueryStats field is None)."""
+        if key not in self.stats:
+            self.note(f"{metric}: not read: the backend's QueryStats has "
+                      f"no {key}")
+            return None
+        return self.stats[key]
+
 
 def _annotate(obj, attr: str, name: str) -> None:
     """Wrap obj.attr in a host trace annotation (traced runs only)."""
@@ -307,22 +320,20 @@ def index_key(cfg: dict, rows: np.ndarray) -> str:
     return h.hexdigest()[:24]
 
 
-def open_service(cfg: dict, rows: np.ndarray, index_dir: str):
-    """The configuration's index over `rows`: (service, shape, built).
+def _saved_graph(cfg: dict, rows: np.ndarray, index_dir: str):
+    """The configuration's graph over `rows`: (home, meta, svc, built).
 
     The first run in a checkout builds the graph through
     SearchService.build from the configuration's fixed `graph_seed`
     (data.py says why it is fixed) and saves it with SearchService.save
     under `index_dir`, keyed by the graph's settings, the rows and the
-    program's source; later runs load it, as a restarted deployment loads
-    its index. A csd deployment's block store is written once beside it
-    from the same graph, with a cache of 1/`cache_divisor` of the graph
-    tables, and reopened by later runs."""
+    program's source (`index_key`); later runs load it, as a restarted
+    deployment loads its index. `svc` is the partitioned service the
+    build made, or None where the graph was already saved."""
     from repro.api import IndexSpec, SearchService
     from repro.core.hnsw_graph import HNSWConfig
 
     home = os.path.join(index_dir, index_key(cfg, rows))
-    part = os.path.join(home, "partitioned")
     meta_path = os.path.join(home, "meta.json")
     svc = None
     built = not os.path.exists(meta_path)
@@ -343,18 +354,70 @@ def open_service(cfg: dict, rows: np.ndarray, index_dir: str):
                           "nbr_list_bytes": int(db.l0_nbrs.shape[-1]
                                                 * db.l0_nbrs.dtype.itemsize),
                           "qscale": svc.spec.qscale}}
-        svc.save(part)
+        svc.save(os.path.join(home, "partitioned"))
         with open(meta_path + ".partial", "w") as f:
             json.dump(meta, f)
         os.replace(meta_path + ".partial", meta_path)
     with open(meta_path) as f:
         meta = json.load(f)
-    shape = dict(meta["shape"])
-    if cfg["backend"] == "partitioned":
-        if svc is None:
-            svc = SearchService.load(part)
-        return svc, shape, built
+    return home, meta, svc, built
+
+
+def _open_partitioned(cfg, rows, index_dir, devices):
+    """The saved graph in HBM, as SearchService.load opens it, on the
+    first chip; the server's pool copies it to each further replica's."""
+    from repro.api import SearchService
+    home, meta, svc, built = _saved_graph(cfg, rows, index_dir)
+    if svc is None:
+        svc = SearchService.load(os.path.join(home, "partitioned"))
+    return svc, dict(meta["shape"]), built
+
+
+def _open_distributed(cfg, rows, index_dir, devices):
+    """The saved graph's partitions sharded over a mesh whose one `model`
+    axis is exactly the cell's chips, searched by graph parallelism. The
+    graph keys name no backend, so a distributed configuration shares the
+    graph of a partitioned one with the same keys."""
+    from jax.sharding import AxisType, Mesh
+
+    from repro.api import IndexSpec, SearchService
+    from repro.api.backends import DistributedBackend
+    from repro.api.service import read_step_leaves
+    from repro.checkpoint import latest_step
+
+    home, meta, built_svc, built = _saved_graph(cfg, rows, index_dir)
+    del built_svc               # the build's copy sits on one chip
+    part = os.path.join(home, "partitioned")
+    spec = dataclasses.replace(IndexSpec.from_json(meta["spec"]),
+                               backend="distributed")
+    mesh = Mesh(np.array(devices), ("model",), axis_types=(AxisType.Auto,))
+    backend = DistributedBackend.from_state(
+        spec, read_step_leaves(part, latest_step(part)), mesh)
+    return SearchService(spec, backend), dict(meta["shape"]), built
+
+
+def _open_exact(cfg, rows, index_dir, devices):
+    """An exact scan over the run's rows, built in every run (no graph,
+    nothing saved)."""
+    from repro.api import IndexSpec, SearchService
+    svc = SearchService.build(rows, IndexSpec(
+        backend="exact", dtype=cfg["dtype"], metric=cfg["metric"]))
+    v = svc.backend.vectors
+    shape = {"dim": int(rows.shape[1]),
+             "row_bytes": int(v.shape[-1] * v.dtype.itemsize),
+             "qscale": svc.spec.qscale}
+    return svc, shape, True
+
+
+def _open_csd(cfg, rows, index_dir, devices):
+    """The saved graph written once to a block store beside it, with a
+    page cache of 1/`cache_divisor` of the graph tables, and reopened by
+    later runs."""
+    from repro.api import IndexSpec, SearchService
     from repro.store.csd import CSDBackend
+
+    home, meta, svc, built = _saved_graph(cfg, rows, index_dir)
+    shape = dict(meta["shape"])
     store = os.path.join(home, f"csd-{cfg['block_size']}-"
                                f"{cfg['cache_divisor']}")
     csd_spec = dataclasses.replace(
@@ -369,7 +432,7 @@ def open_service(cfg: dict, rows: np.ndarray, index_dir: str):
         built = True
         shutil.rmtree(store, ignore_errors=True)
         if svc is None:
-            svc = SearchService.load(part)
+            svc = SearchService.load(os.path.join(home, "partitioned"))
         backend = CSDBackend.from_partitioned(svc.backend.pdb, csd_spec)
         del svc
         open(done, "w").close()
@@ -378,6 +441,39 @@ def open_service(cfg: dict, rows: np.ndarray, index_dir: str):
                                  for d, _, fs in os.walk(store) for f in fs),
                  cache_bytes=csd_spec.cache_bytes)
     return SearchService(csd_spec, backend), shape, built
+
+
+# `backend` of a configuration -> how the benchmark opens it
+OPENERS = {"partitioned": _open_partitioned,
+           "distributed": _open_distributed,
+           "exact": _open_exact,
+           "csd": _open_csd}
+
+
+def refusal(cfg: dict, chips: int) -> str | None:
+    """Why the configuration cannot run in a cell of `chips` chips, or
+    None: a backend the benchmark does not open, more `replicas` (default
+    1) than chips, or a distributed index whose partitions do not divide
+    over its mesh, which is the cell's chips."""
+    backend = cfg["backend"]
+    if backend not in OPENERS:
+        return (f"backend {backend!r} is not one the benchmark opens "
+                f"({', '.join(sorted(OPENERS))})")
+    replicas = cfg.get("replicas", 1)
+    if not isinstance(replicas, int) or replicas < 1:
+        return f"replicas={replicas!r} is not a whole number of at least 1"
+    if replicas > chips:
+        return f"{replicas} replicas; the cell has {chips} chips"
+    if backend == "distributed" and cfg["num_partitions"] % chips:
+        return (f"num_partitions={cfg['num_partitions']} does not divide "
+                f"over a mesh of the cell's {chips} chips")
+    return None
+
+
+def open_service(cfg: dict, rows: np.ndarray, index_dir: str, devices):
+    """The configuration's index over `rows` on `devices`, the cell's
+    chips, opened as its `backend` says: (service, shape, built)."""
+    return OPENERS[cfg["backend"]](cfg, rows, index_dir, devices)
 
 
 def warm_shapes(traffic, params: dict, max_batch: int) -> list[int]:
@@ -392,6 +488,25 @@ def warm_shapes(traffic, params: dict, max_batch: int) -> list[int]:
     return sorted({bucket_size(n, max_batch) for n in sizes})
 
 
+def warm_replicas(svc, cfg: dict, shapes: list[int], pool: np.ndarray):
+    """The configuration's `replicas` of `svc` in a pool for the server,
+    each distinct service searched once at every batch shape in `shapes`
+    (replicas of a distributed index share one): (pool, services)."""
+    import jax
+
+    from repro.api import SearchRequest
+    from repro.serve.dispatch import ReplicaPool
+    replicas = ReplicaPool.replicate(svc, cfg.get("replicas", 1))
+    services = list({id(r.service): r.service
+                     for r in replicas.replicas}.values())
+    for s in services:
+        for b in shapes:
+            r = s.search(SearchRequest(pool[:b], k=cfg["k"], ef=cfg["ef"],
+                                       with_stats=True))
+            jax.block_until_ready((r.ids, r.dists))
+    return replicas, services
+
+
 def _snapshot(server):
     """Counters the per-layer metrics difference over a window."""
     def snap():
@@ -403,6 +518,14 @@ def _snapshot(server):
                                       "cache_misses", "bytes_read")}
         return out
     return snap
+
+
+def _held_devices(backend) -> list[int]:
+    """Ids of the devices that hold the backend's arrays."""
+    import jax
+    return sorted({d.id for v in vars(backend).values()
+                   for a in jax.tree.leaves(v) if isinstance(a, jax.Array)
+                   for d in a.devices()})
 
 
 def _delta(a: dict, b: dict) -> dict:
@@ -421,14 +544,19 @@ def run(argv=None, *, bench: Bench | None = None, need_tpu: bool = True
     bench = bench or Bench()
     cell = bench.workload(args.workload)
     cfg = bench.config(cell["config"])
+    why = refusal(cfg, cell["chips"])
+    if why is not None:
+        print(f"run_cell: {args.workload}: config {cell['config']!r}: "
+              f"{why}", file=sys.stderr)
+        return 2
     traffic = bench.traffic(cell["traffic"])
     wanted = bench.metrics(args.workload, bool(args.trace))
     readers = {m["name"]: bench.reader(m["name"]) for m in wanted}
 
     import jax
     devices = jax.devices()
-    if need_tpu and (devices[0].platform != "tpu"
-                     or len(devices) < cell["chips"]):
+    if ((need_tpu and devices[0].platform != "tpu")
+            or len(devices) < cell["chips"]):
         print(f"run_cell: {args.workload} needs {cell['chips']} TPU chip(s); "
               f"JAX found {len(devices)} {devices[0].platform!r} device(s)",
               file=sys.stderr)
@@ -442,7 +570,6 @@ def run(argv=None, *, bench: Bench | None = None, need_tpu: bool = True
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     log(f"compile cache: {cache_dir}")
 
-    from repro.api import SearchRequest
     from repro.serve import SearchServer
 
     t_jax = time.perf_counter()
@@ -450,27 +577,27 @@ def run(argv=None, *, bench: Bench | None = None, need_tpu: bool = True
                                   cfg["dim"])
     work = tempfile.mkdtemp(prefix="bench_chip_")
     tracer = Tracer(work) if args.trace else None
-    server = None
+    server = replicas = None
     try:
         t = time.perf_counter()
-        svc, shape, built = open_service(cfg, rows, bench.index_dir)
+        svc, shape, built = open_service(cfg, rows, bench.index_dir, used)
         index_s = time.perf_counter() - t
         log(f"index: {'built' if built else 'loaded'} n={cfg['n']} "
-            f"backend={cfg['backend']} partitions={cfg['num_partitions']} "
+            f"backend={cfg['backend']} "
+            f"partitions={cfg.get('num_partitions')} "
+            f"replicas={cfg.get('replicas', 1)} chips={len(used)} "
             f"seconds={index_s:.3f} shape={json.dumps(shape)}")
         t_warm = time.perf_counter()
         shapes = warm_shapes(traffic, cell["params"], cfg["max_batch"])
-        log(f"warm batch shapes: {shapes}")
-        for b in shapes:
-            r = svc.search(SearchRequest(pool[:b], k=cfg["k"], ef=cfg["ef"],
-                                         with_stats=True))
-            jax.block_until_ready((r.ids, r.dists))
+        replicas, services = warm_replicas(svc, cfg, shapes, pool)
+        log(f"warm batch shapes: {shapes} on {len(services)} service(s)")
         t_served = time.perf_counter()
-        server = SearchServer(svc, max_batch=cfg["max_batch"],
+        server = SearchServer(replicas, max_batch=cfg["max_batch"],
                               max_wait_ms=cfg["max_wait_ms"])
+        replicas = None                 # the server closes its pool
         if tracer is not None:
-            _annotate(server.pool.replicas[0].service, "search",
-                      "bench.search")
+            for s in services:
+                _annotate(s, "search", "bench.search")
             _annotate(server.queue, "collect", "bench.batcher.collect")
         compiles = []
         jax.monitoring.register_event_duration_secs_listener(
@@ -493,10 +620,15 @@ def run(argv=None, *, bench: Bench | None = None, need_tpu: bool = True
         memory_peak = max(int(m.get("peak_bytes_in_use", 0)) for m in mem)
         in_window = [c for c in compiles if load.t_open <= c <= load.t_close]
         print(f"compiles in window: {len(in_window)}", file=sys.stderr)
-        cache_peak = None
-        if cfg["backend"] == "csd":
-            cache_peak = server.pool.replicas[0].service.backend.reader \
-                .cache.snapshot()["peak_bytes"]
+        for rep_stats, rep in zip(server.pool.stats(), server.pool.replicas):
+            log(f"replica {rep.rid}: devices "
+                f"{_held_devices(rep.service.backend)} batches "
+                f"{rep_stats['batches']} queries {rep_stats['queries']}")
+        caches = [rep.service.backend.reader.cache
+                  for rep in server.pool.replicas
+                  if hasattr(rep.service.backend, "reader")]
+        cache_peak = (max(c.snapshot()["peak_bytes"] for c in caches)
+                      if caches else None)
         server.shutdown(drain=False)
         reader_ = getattr(svc.backend, "reader", None)
         if reader_ is not None:
@@ -509,6 +641,8 @@ def run(argv=None, *, bench: Bench | None = None, need_tpu: bool = True
     finally:
         if server is not None:
             server.shutdown(drain=False)
+        elif replicas is not None:
+            replicas.close()
         shutil.rmtree(work, ignore_errors=True)
 
     # -- correct: every answer against the exact scan -------------------------
@@ -535,13 +669,13 @@ def run(argv=None, *, bench: Bench | None = None, need_tpu: bool = True
 
     # -- metrics ---------------------------------------------------------------
     layer_records = load.in_window(load.layer_open, load.layer_close)
-    stats = {"queries": 0, "hops": 0, "dist_calcs": 0}
     layer_q = [r.fut.result() for r in layer_records
                if r.fut.done() and r.fut.exception() is None]
-    for x in layer_q:
-        stats["queries"] += 1
-        stats["hops"] += int(np.asarray(x.stats.hops))
-        stats["dist_calcs"] += int(np.asarray(x.stats.dist_calcs))
+    stats = {"queries": len(layer_q)}
+    for key in ("hops", "dist_calcs"):   # one a backend does not count: absent
+        got = [getattr(x.stats, key, None) for x in layer_q]
+        if all(v is not None for v in got):
+            stats[key] = sum(int(np.asarray(v)) for v in got)
     ctx = Context(
         setup_s=load.t_open - T_START,
         window=(load.t_open, load.t_close),

@@ -5,9 +5,10 @@ highest offered rate whose backlog does not grow over a window.
     python3 benchmarks/chip/sweep.py --workload hbm.poisson --seed 5 \
         --seconds 15 --rates 150 200 250 300 350
 
-Opens the cell's index once, warms its batch shapes, then offers
-each rate through the cell's own traffic kind for --seconds. Per rate it
-prints one JSON line: answers per second, p50/p95 latency from when each
+Opens the cell's index once on one chip (a configuration that needs
+more is refused), warms its batch shapes, then offers each rate
+through the cell's own traffic kind for --seconds. Per rate it prints
+one JSON line: answers per second, p50/p95 latency from when each
 request was due, and the backlog (requests due and not yet answered) at
 the middle and the end of the window. A run of the benchmark never calls
 it; the knee and the cell's rate are written into the workload file.
@@ -47,6 +48,10 @@ def main(argv=None) -> int:
         cell = json.load(f)
     cfg = bench.config(cell["config"])
     traffic = bench.traffic(cell["traffic"])
+    why = run_cell.refusal(cfg, 1)
+    if why is not None:
+        print(f"sweep: {why}", file=sys.stderr)
+        return 2
 
     import jax
     from repro.api import SearchRequest
@@ -55,7 +60,8 @@ def main(argv=None) -> int:
     enable_compile_cache()
     rows, pool = data.bigann_rows(cfg["n"], cfg["query_pool"], args.seed,
                                   cfg["dim"])
-    svc, _, _ = run_cell.open_service(cfg, rows, bench.index_dir)
+    svc, _, _ = run_cell.open_service(cfg, rows, bench.index_dir,
+                                      jax.devices()[:1])
     for b in run_cell.warm_shapes(traffic, cell["params"], cfg["max_batch"]):
         r = svc.search(SearchRequest(pool[:b], k=cfg["k"], ef=cfg["ef"],
                                      with_stats=True))
