@@ -11,6 +11,12 @@ it (`WINDOW`). Inside it:
   device_ops  device seconds per operation, the ten largest, each named by
               the first characters of its HLO text; ops nest (a while
               loop holds its body's ops), so these overlap;
+  collective_s  device seconds of collective operations (all-gather,
+              all-reduce, reduce-scatter, collective-permute, all-to-all,
+              and their -start and -done halves), found by the opcode in
+              an op's HLO text: the union of their intervals on each
+              device, summed over the devices, so at most busy_s times
+              devices;
   idle_gaps   the gaps between busy intervals on the first busy device,
               by what the host was doing: for each of the benchmark's own
               host annotations (`bench.*`), the idle seconds it overlaps,
@@ -36,6 +42,11 @@ NO_HOST_EVENT = "no bench annotation"
 OP_NAME_CHARS = 120
 _DEVICE = re.compile(r"^/device:TPU:\d+$")
 _SUFFIX = re.compile(r"\(.*\)$")
+# an op's HLO text: `%name = <shape> <opcode>(<operands>), <attributes>`;
+# a shape's layout holds no lower-case word right before a `(`
+_OPCODE = re.compile(r" = .*?\s([a-z][a-z0-9-]*)\(")
+COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter",
+               "collective-permute", "all-to-all")
 
 
 def module_name(raw: str) -> str:
@@ -77,6 +88,14 @@ def _union(spans) -> list[tuple[float, float]]:
         else:
             out.append([s, e])
     return [(s, e) for s, e in out]
+
+
+def is_collective(op_name: str) -> bool:
+    """Whether the HLO opcode of a device op, named by its HLO text, is a
+    collective or one half of an asynchronous one."""
+    m = _OPCODE.search(op_name)
+    return m is not None and any(
+        m[1] in (c, c + "-start", c + "-done") for c in COLLECTIVES)
 
 
 def _line(plane: dict, name: str):
@@ -125,6 +144,7 @@ def reduce(planes: list[dict], top: int = 10) -> dict | None:
         return None
     lo, hi = marks[0]
     busy, modules, ops, gaps_of = [], {}, {}, None
+    collective = 0.0
     for dev in devices:
         mods = _line(dev, "XLA Modules") or []
         op_line = _line(dev, "XLA Ops")
@@ -141,6 +161,9 @@ def reduce(planes: list[dict], top: int = 10) -> dict | None:
             m["count"] += 1
         for name, s, e in _clip(op_line or [], lo, hi):
             ops[name] = ops.get(name, 0.0) + (e - s) / 1e9
+        collective += sum(e - s for s, e in _union(
+            (s, e) for name, s, e in _clip(op_line or [], lo, hi)
+            if is_collective(name)))
         if gaps_of is None:
             edges = [lo] + [x for span in merged for x in span] + [hi]
             gaps_of = [(edges[i], edges[i + 1])
@@ -162,4 +185,5 @@ def reduce(planes: list[dict], top: int = 10) -> dict | None:
         "modules": modules,
         "device_ops": [[short(n), s] for n, s in rank(ops)],
         "idle_gaps": [[n, ns / 1e9] for n, ns in rank(named)],
+        "collective_s": collective / 1e9,
     }

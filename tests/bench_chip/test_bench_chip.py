@@ -448,6 +448,63 @@ def test_trace_reduce_on_a_recorded_tpu_trace():
         trace_reduce.NO_HOST_EVENT: pytest.approx(12547817e-9)}
 
 
+def _op(opcode, shape="s32[64,10]{1,0}"):
+    return f"%{opcode}.1 = {shape} {opcode}({shape} %p.2), dimensions={{1}}"
+
+
+def test_trace_reduce_sums_collectives_over_devices_in_the_window():
+    """Window 0-100 ns on four devices. Collectives by opcode, whatever
+    the op's own name: an all-gather 90-110 (10 in the window) and an
+    all-reduce 20-30 on TPU:0; an all-gather-start 10-20 nested in a
+    while loop 0-50 and its -done 40-45 on TPU:1; a collective-permute
+    -5-5 (5 in) and a reduce-scatter 50-60 on TPU:2; no collective on
+    TPU:3. A copy-start is no collective, nor is a fusion named after
+    one. Summed over the devices: 10+10 + 10+5 + 5+10 = 50 ns."""
+    host = _plane("/host:CPU", main=[("bench.window", 0.0, 100.0)])
+    ag_start = _op("all-gather-start", "(s32[64,10]{1,0}, s32[64,40]{1,0})")
+    dev = [
+        [(_op("all-gather"), 90.0, 20.0), (_op("all-reduce"), 20.0, 10.0),
+         (_op("fusion"), 30.0, 20.0)],
+        [("%while.3 = (s32[], s32[64,40]{1,0}) while((s32[], s32[64,40]{1,0})"
+          " %t), condition=%c, body=%b", 0.0, 50.0),
+         (ag_start, 10.0, 10.0), (_op("all-gather-done"), 40.0, 5.0)],
+        [(_op("collective-permute"), -5.0, 10.0),
+         (_op("reduce-scatter"), 50.0, 10.0),
+         ("%copy-start.2 = (f32[8]{0}, f32[8]{0}, u32[]) copy-start(f32[8]{0}"
+          " %x)", 60.0, 10.0)],
+        [("%all-gather-fusion = s32[64,40]{1,0} fusion(s32[64,10]{1,0} %p),"
+          " kind=kLoop, calls=%f", 0.0, 40.0)],
+    ]
+    planes = [host] + [_plane(f"/device:TPU:{i}", **{"XLA Ops": ev})
+                       for i, ev in enumerate(dev)]
+    r = trace_reduce.reduce(planes)
+    assert r["devices"] == 4
+    assert r["collective_s"] == pytest.approx(50e-9)
+    assert r["collective_s"] <= r["busy_s"] * r["devices"]
+
+
+def test_trace_reduce_takes_a_collectives_overlap_once():
+    """An all-reduce-start 0-30 and an all-reduce-done 20-40 overlap by
+    10 ns: the device spent 40 ns in collectives, not 50."""
+    planes = [_plane("/host:CPU", main=[("bench.window", 0.0, 100.0)]),
+              _plane("/device:TPU:0", **{"XLA Ops": [
+                  (_op("all-reduce-start"), 0.0, 30.0),
+                  (_op("all-reduce-done"), 20.0, 20.0)]})]
+    r = trace_reduce.reduce(planes)
+    assert r["collective_s"] == pytest.approx(40e-9)
+    assert r["busy_s"] == pytest.approx(40e-9)
+
+
+def test_trace_reduce_finds_no_collective_on_one_chip():
+    """The one-chip fixture trace ran no collective; its other fields
+    are those test_trace_reduce_on_a_recorded_tpu_trace counted."""
+    r = trace_reduce.reduce(trace_reduce.load(os.path.join(FIX, "trace")))
+    assert r["collective_s"] == 0.0
+    assert set(r) == {"window_s", "busy_s", "devices", "modules",
+                      "device_ops", "idle_gaps", "collective_s"}
+    assert r["busy_s"] == pytest.approx(14695e-9)
+
+
 def test_trace_reduce_finds_nothing_off_the_chip():
     assert trace_reduce.reduce([_plane("/host:CPU", main=[
         ("bench.window", 0.0, 10.0)])]) is None

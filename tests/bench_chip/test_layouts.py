@@ -1,7 +1,8 @@
 """CPU tests of how the chip benchmark opens a configuration: the backend
 it names, its layout over the cell's chips (`replicas`, a distributed
-mesh), the refusals of what a cell cannot hold, and counters that a
-backend does not track.
+mesh), the refusals of what a cell cannot hold, counters that a backend
+does not track, the faults of the distributed path, and the readers of
+the distributed cells.
 
     PYTHONPATH=src JAX_PLATFORMS=cpu python -m pytest -q tests/bench_chip/test_layouts.py
 """
@@ -102,14 +103,47 @@ def test_every_replica_serves_on_its_own_device(four):
         four["tiny.replicas"]["result"]["attempted"])
 
 
-def test_untracked_hops_are_absent_with_a_note(four):
-    """The distributed backend counts distance evaluations, not hops."""
+# QueryStats field -> the per-layer metric of the fixture cells that reads it
+COUNTER_METRICS = {"hops": "traversal.hops_per_query",
+                   "dist_calcs": "traversal.dist_calcs_per_query"}
+
+
+def test_reported_counters_are_read_and_the_others_noted(four):
+    """The harness's contract on the four-device mesh, whatever the
+    distributed backend counts: a counter that every answer carries is
+    read and positive; one that it does not carry is absent from the
+    result, with Context.counter's note and no other."""
     run = four["tiny.dist"]
-    assert "traversal.hops_per_query" not in run["result"]["metrics"]
-    assert run["result"]["metrics"]["traversal.dist_calcs_per_query"][
-        "value"] > 0
-    assert run["notes"] == ["traversal.hops_per_query: not read: the "
-                            "backend's QueryStats has no hops"]
+    assert "dist_calcs" in run["reported"]
+    metrics, notes = run["result"]["metrics"], []
+    for key, metric in COUNTER_METRICS.items():
+        if key in run["reported"]:
+            assert metrics[metric]["value"] > 0
+        else:
+            assert metric not in metrics
+            notes.append(f"{metric}: not read: the backend's QueryStats "
+                         f"has no {key}")
+    assert sorted(run["notes"]) == sorted(notes)
+
+
+@pytest.mark.parametrize("fault,check", [
+    # each device merges its own candidates alone, so most of a query's
+    # true neighbours, on the other devices, are missed
+    ("no_exchange", "miss_at_10"),
+    ("half_batch", "miss_at_10"),
+    ("altered", "dist_gap"),
+])
+def test_a_fault_of_the_distributed_path_is_not_correct(four, fault, check):
+    """The faults of the four-chip cell, planted under a run of tiny.dist:
+    `correct` comes out false, on the number that catches each, where the
+    sound run of the same seed holds it."""
+    run = four[f"tiny.dist.{fault}"]
+    assert run["rc"] == 0, run["stderr_tail"]
+    assert run["result"]["correct"] is False
+    assert run["result"]["checks"][check]["value"] > (
+        run["result"]["checks"][check]["limit"])
+    sound = four["tiny.dist"]["result"]["checks"][check]
+    assert sound["value"] <= sound["limit"]
 
 
 def test_distributed_reuses_the_saved_graph(four):
@@ -279,3 +313,57 @@ def test_an_untracked_counter_reads_nothing_with_a_note(metric, missing):
     assert b.reader(metric).read(ctx) is None
     assert any(n.endswith(f"not read: the backend's QueryStats has no "
                           f"{missing}") for n in ctx.notes)
+
+
+# -- the readers of the distributed cells --------------------------------------
+
+DIST_READERS = ("distributed.collective_share",
+                "traversal.dist_calcs_per_query.dist",
+                "device.idle_share.dist", "serve.batch_host_ms.dist")
+
+
+@pytest.mark.parametrize("metric", DIST_READERS)
+def test_a_distributed_reader_reads_nothing_without_its_source(metric):
+    """No trace, no spans recorded and no counted queries: nothing, and a
+    note only where the harness gives one (an absent counter)."""
+    ctx = _ctx({"queries": 0})
+    assert run_cell.Bench().reader(metric).read(ctx) is None
+    assert all(n.startswith(metric.removesuffix(".dist")) for n in ctx.notes)
+
+
+@pytest.mark.parametrize("metric,source", [
+    ("traversal.dist_calcs_per_query.dist", "traversal.dist_calcs_per_query"),
+    ("device.idle_share.dist", "device.idle_share"),
+])
+def test_a_distributed_alias_reads_as_its_source(metric, source):
+    b = run_cell.Bench()
+    trace = {"window_s": 2.0, "busy_s": 1.5, "devices": 4,
+             "collective_s": 1.0, "modules": {}}
+    ctx = _ctx({"queries": 10, "hops": 1000, "dist_calcs": 20250}, trace)
+    assert b.reader(metric).read(ctx) == b.reader(source).read(ctx) > 0
+
+
+@pytest.mark.parametrize("busy,devices,coll,want", [
+    (1.5, 4, 1.2, 20.0),           # 1.2 of 6.0 device-seconds
+    (0.5, 1, 0.5, 100.0),          # all of one device's busy time
+    (2.0, 4, 8.0, 100.0),          # every busy second on every device
+])
+def test_collective_share_is_a_share_of_busy_device_time(busy, devices,
+                                                         coll, want):
+    trace = {"window_s": 2.0, "busy_s": busy, "devices": devices,
+             "collective_s": coll, "modules": {}}
+    ctx = _ctx({"queries": 10}, trace)
+    got = run_cell.Bench().reader("distributed.collective_share").read(ctx)
+    assert got == pytest.approx(want) and 0 < got <= 100
+    assert ctx.notes == []
+
+
+def test_collective_share_notes_a_trace_without_collectives():
+    trace = {"window_s": 2.0, "busy_s": 1.0, "devices": 1,
+             "collective_s": 0.0, "modules": {}}
+    ctx = _ctx({"queries": 10}, trace)
+    assert run_cell.Bench().reader(
+        "distributed.collective_share").read(ctx) is None
+    assert ctx.notes == ["distributed.collective_share: not read: no "
+                         "collective op ran on the devices in the traced "
+                         "window"]
