@@ -8,8 +8,9 @@ paper's hardware modifications carry over:
   * parallel distance calculator  -> MXU-friendly ||q-x||^2 = ||x||^2 - 2 x.q + ||q||^2
                                      over a whole (padded) neighbor list at once
   * parallel insertion sort via   -> rank-based merge of two sorted arrays:
-    comparison bit-vector            pos = index + searchsorted(other)
-                                     (searchsorted == popcount of "smaller" bits)
+    comparison bit-vector            pos = index + #(other < self), the
+                                     popcount of "smaller" bits, counted by
+                                     one comparison fusion (no binary search)
   * multi-query processing        -> vmap over the query batch; the masked
                                      lockstep while_loop is the many-module
                                      generalization of the paper's 2 modules
@@ -122,15 +123,20 @@ def merge_sorted(ad, ai, bd, bi):
     """Merge two ascending (dist, id) arrays; ties keep `a` first.
 
     The paper's parallel insertion sort computes an insert position as the
-    popcount of a comparison bit-vector; ``searchsorted`` computes exactly
-    that rank, vectorized over every element of both lists at once.
+    popcount of a comparison bit-vector, and so do these ranks:
+    ``pa_i = i + #(b_j < a_i)`` and ``pb_j = j + #(a_i <= b_j)``, counted
+    over every pair of both lists in one comparison fusion.
+    ``searchsorted``'s ``compare_all`` method counts them with the sort
+    comparators (-0.0 equals 0.0, +inf pads last); its default binary search
+    gives the same ranks through a loop of per-element gathers, which
+    dominated the layer-0 hop on the TPU.
     """
     na, nb = ad.shape[0], bd.shape[0]
     pa = jnp.arange(na, dtype=jnp.int32) + jnp.searchsorted(
-        bd, ad, side="left"
+        bd, ad, side="left", method="compare_all"
     ).astype(jnp.int32)
     pb = jnp.arange(nb, dtype=jnp.int32) + jnp.searchsorted(
-        ad, bd, side="right"
+        ad, bd, side="right", method="compare_all"
     ).astype(jnp.int32)
     od = jnp.zeros(na + nb, ad.dtype).at[pa].set(ad).at[pb].set(bd)
     oi = jnp.zeros(na + nb, ai.dtype).at[pa].set(ai).at[pb].set(bi)

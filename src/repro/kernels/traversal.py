@@ -27,8 +27,10 @@ This kernel is the jax_pallas analogue of that engine. One invocation:
 Bit-parity is load-bearing, so the in-kernel merge/sort are the *same
 mathematics* as core/search.py's `merge_sorted` / stable argsort, expressed
 as comparison-matrix rank computations (the paper's parallel insertion sort
-computes insert positions as popcounts of comparison bit-vectors — §5.2.4):
-``searchsorted(b, a, 'left') == #(b_j < a_i)`` and stable-argsort position
+computes insert positions as popcounts of comparison bit-vectors — §5.2.4).
+Both paths count the merge ranks ``#(b_j < a_i)`` and ``#(a_i <= b_j)`` as
+comparison counts under the same order (``<``; -0.0 equals 0.0, +inf is
+last); the stable-argsort position is
 ``pos_i == #(d_j < d_i) + #(j < i, d_j == d_i)``. Identical outputs, but
 matmul/reduction-shaped instead of sort-shaped. Mosaic does not lower the
 kernel yet (its (1, w) lane blocks, the DMA index read out of a vector, and
@@ -53,7 +55,7 @@ __all__ = ["fused_traversal_pallas"]
 
 # ---------------------------------------------------------------------------
 # In-kernel primitives: rank-based sort/merge + visited bitmap, identical in
-# value to core/search.py's argsort/searchsorted/scatter formulations.
+# value to core/search.py's argsort/comparison-count/scatter formulations.
 # ---------------------------------------------------------------------------
 
 
@@ -91,9 +93,10 @@ def _stable_sort_pairs(d, ids):
 def _rank_merge(ad, ai, bd, bi):
     """Merge two ascending (dist, id) arrays; ties keep `a` first.
 
-    Value-identical to core.search.merge_sorted: the searchsorted ranks are
-    computed as comparison-matrix popcounts (paper §5.2.4's comparison
-    bit-vector), and the position scatter as one-hot masked reductions.
+    Value-identical to core.search.merge_sorted: both count the ranks as
+    comparison-matrix popcounts under the same order (paper §5.2.4's
+    comparison bit-vector); here the position scatter is one-hot masked
+    reductions too.
     """
     na, nb = ad.shape[0], bd.shape[0]
     n = na + nb

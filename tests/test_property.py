@@ -1,5 +1,6 @@
 """Hypothesis property tests on the system's invariants."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -53,6 +54,73 @@ def test_merge_sorted_tie_break_prefers_existing(a, b):
     marks = np.concatenate([np.zeros(len(ad)), np.ones(len(bd))])
     order = np.argsort(d, kind="stable")
     np.testing.assert_array_equal(np.asarray(oi), marks[order].astype(np.int32))
+
+
+def _merge_by_binary_search(ad, ai, bd, bi):
+    """merge_sorted's ranks by searchsorted's binary search (method "scan")."""
+    na, nb = ad.shape[0], bd.shape[0]
+    pa = jnp.arange(na, dtype=jnp.int32) + jnp.searchsorted(
+        bd, ad, side="left", method="scan").astype(jnp.int32)
+    pb = jnp.arange(nb, dtype=jnp.int32) + jnp.searchsorted(
+        ad, bd, side="right", method="scan").astype(jnp.int32)
+    od = jnp.zeros(na + nb, ad.dtype).at[pa].set(ad).at[pb].set(bd)
+    oi = jnp.zeros(na + nb, ai.dtype).at[pa].set(ai).at[pb].set(bi)
+    return od, oi
+
+
+def _tie_heavy_sorted(rng, n):
+    """[4, 64, n] ascending rows drawn from a few values (-0.0 and 0.0
+    among them), each row's tail +inf-padded, as the hop's lists are."""
+    vals = np.array([-0.0, 0.0, 0.5, 1.0, 3.0, 1e6], np.float32)
+    d = rng.choice(vals, size=(4, 64, n)).astype(np.float32)
+    pad = rng.integers(0, n + 1, size=(4, 64, 1))
+    d = np.where(np.arange(n) >= n - pad, np.float32(np.inf), d)
+    return np.sort(d, axis=-1, kind="stable")
+
+
+# the layer-0 hop's widths (final list ef=40 and candidate list ef+maxM0=72
+# against maxM0=32 new candidates) and the exact scan's k against k
+MERGE_WIDTHS = [(72, 32), (40, 32), (10, 10)]
+
+
+@pytest.mark.parametrize("na,nb", MERGE_WIDTHS)
+def test_merge_sorted_batched_matches_binary_search_and_oracle(na, nb):
+    rng = np.random.default_rng(na * 100 + nb)
+    ad, bd = _tie_heavy_sorted(rng, na), _tie_heavy_sorted(rng, nb)
+    ai = np.broadcast_to(np.arange(na, dtype=np.int32), ad.shape)
+    bi = np.broadcast_to(1000 + np.arange(nb, dtype=np.int32), bd.shape)
+    args = tuple(map(jnp.asarray, (ad, ai, bd, bi)))
+    got_d, got_i = map(np.asarray,
+                       jax.jit(jax.vmap(jax.vmap(merge_sorted)))(*args))
+    ref_d, ref_i = map(np.asarray, jax.jit(jax.vmap(jax.vmap(
+        _merge_by_binary_search)))(*args))
+    # numpy oracle: a stable sort of the concatenation keeps `a` first
+    cat_d = np.concatenate([ad, bd], axis=-1)
+    order = np.argsort(cat_d, axis=-1, kind="stable")
+    ora_d = np.take_along_axis(cat_d, order, -1)
+    ora_i = np.take_along_axis(np.concatenate([ai, bi], axis=-1), order, -1)
+    for want_d, want_i in ((ref_d, ref_i), (ora_d, ora_i)):
+        # bit for bit: -0.0 and 0.0 must land where the reference puts them
+        np.testing.assert_array_equal(got_d.view(np.uint32),
+                                      want_d.view(np.uint32))
+        np.testing.assert_array_equal(got_i, want_i)
+
+
+def _compiled_merge_text(merge, na, nb):
+    args = [jax.ShapeDtypeStruct((64, n), dt) for n, dt in
+            ((na, jnp.float32), (na, jnp.int32),
+             (nb, jnp.float32), (nb, jnp.int32))]
+    return jax.jit(jax.vmap(merge)).lower(*args).compile().as_text()
+
+
+def test_merge_sorted_compiles_without_loops_or_gathers():
+    """The ranks are one comparison fusion: a binary search's `while` of
+    per-element gathers (the slowest op of the TPU's layer-0 hop) would
+    show in the compiled program."""
+    text = _compiled_merge_text(merge_sorted, 72, 32)
+    assert "while(" not in text and "gather(" not in text
+    # the guard sees the loop where it is
+    assert "while(" in _compiled_merge_text(_merge_by_binary_search, 72, 32)
 
 
 @given(st.lists(st.integers(min_value=0, max_value=255), min_size=1,
